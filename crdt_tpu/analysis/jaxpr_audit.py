@@ -396,8 +396,8 @@ def builtin_targets(include_sharded: bool = True) -> List[AuditTarget]:
 
     # The per-shard body of parallel/fanin.py's _pallas_fanin_block
     # (split -> pallas_fanin_batch -> join) audited at the per-device
-    # shard shape. This is the golden-pinned target: it traces on any
-    # jax, whereas the full shard_map step below needs `jax.P`.
+    # shard shape. This is the golden-pinned target: it traces on one
+    # device, whereas the full shard_map steps below need a mesh.
     targets.append(AuditTarget(
         name="parallel.pallas_fanin_block[per-shard]",
         notes="parallel/fanin.py _pallas_fanin_block per-device body: "
@@ -407,44 +407,29 @@ def builtin_targets(include_sharded: bool = True) -> List[AuditTarget]:
         build=_build_pallas_block_per_shard))
 
     if include_sharded and len(jax.devices()) >= 8:
-        try:
-            from ..parallel import fanin as _pf  # noqa: F401
-            have_parallel = True
-        except ImportError:
-            # parallel/ targets a newer jax (`jax.P`, top-level
-            # shard_map); on older versions the per-shard body above
-            # still covers the kernel path.
-            have_parallel = False
-        if have_parallel:
-            targets.append(AuditTarget(
-                name="parallel.sharded_fanin[mesh2x4]",
-                notes="shard_map + psum/pmax collective fan-in block",
-                build=_build_sharded_fanin))
-            targets.append(AuditTarget(
-                name="parallel.sharded_pallas_fanin[mesh2x4]",
-                notes="per-shard Mosaic batch kernel inside the "
-                      "collective step (parallel/fanin.py "
-                      "_pallas_fanin_block); trace-only",
-                build=_build_sharded_pallas_fanin))
+        targets.append(AuditTarget(
+            name="parallel.sharded_fanin[mesh2x4]",
+            notes="shard_map + psum/pmax collective fan-in block",
+            build=_build_sharded_fanin))
+        targets.append(AuditTarget(
+            name="parallel.sharded_pallas_fanin[mesh2x4]",
+            notes="per-shard Mosaic batch kernel inside the "
+                  "collective step (parallel/fanin.py "
+                  "_pallas_fanin_block); trace-only",
+            build=_build_sharded_pallas_fanin))
 
     # The pod-local collective join only needs a 2-member mesh, so it
     # audits on any multi-device (or virtual-device) host even where
     # the 8-device fan-in meshes cannot build.
     if include_sharded and len(jax.devices()) >= 2:
-        try:
-            from ..parallel import collective as _pc  # noqa: F401
-            have_collective = True
-        except ImportError:
-            have_collective = False
-        if have_collective:
-            targets.append(AuditTarget(
-                name="parallel.collective_join[member2]",
-                notes="pod-local group anti-entropy: shard_map lex-max "
-                      "clock join + typed-semantics collectives "
-                      "(gcounter/pncounter/orset pmax, mvreg "
-                      "all_gather union) + in-program digest leaves "
-                      "(parallel/collective.py, docs/COLLECTIVE.md)",
-                build=_build_collective_join))
+        targets.append(AuditTarget(
+            name="parallel.collective_join[member2]",
+            notes="pod-local group anti-entropy: shard_map lex-max "
+                  "clock join + typed-semantics collectives "
+                  "(gcounter/pncounter/orset pmax, mvreg "
+                  "all_gather union) + in-program digest leaves "
+                  "(parallel/collective.py, docs/COLLECTIVE.md)",
+            build=_build_collective_join))
 
     return targets
 
@@ -553,21 +538,23 @@ def _build_collective_join():
 
     mesh = pc.make_collective_mesh(2)
 
-    def member_store():
-        return DenseStore(lt=np.zeros(_N, np.int64),
-                          node=np.zeros(_N, np.int32),
-                          val=np.zeros(_N, np.int64),
-                          mod_lt=np.zeros(_N, np.int64),
-                          mod_node=np.zeros(_N, np.int32),
-                          occupied=np.zeros(_N, bool),
-                          tomb=np.zeros(_N, bool))
+    # The program's own input: both members' lanes, [2*N] over the
+    # member axis.
+    group = DenseStore(lt=np.zeros(2 * _N, np.int64),
+                       node=np.zeros(2 * _N, np.int32),
+                       val=np.zeros(2 * _N, np.int64),
+                       mod_lt=np.zeros(2 * _N, np.int64),
+                       mod_node=np.zeros(2 * _N, np.int32),
+                       occupied=np.zeros(2 * _N, bool),
+                       tomb=np.zeros(2 * _N, bool))
 
     # has_sem=True so the audit walks every typed join branch (the
     # untyped program is a strict subset). Trace the jitted program
-    # itself — the host wrapper only adds ledger accounting.
+    # itself — the host wrapper only places lanes and adds ledger
+    # accounting.
     step = pc.make_collective_join(mesh, True, 8, donate=False)
     return jax.make_jaxpr(step.jitted)(
-        (member_store(), member_store()), np.zeros(_N, np.int8),
+        group, np.zeros(_N, np.int8),
         np.zeros(2, np.int64), np.zeros(2, np.int32), np.int64(0))
 
 

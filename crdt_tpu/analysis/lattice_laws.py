@@ -344,54 +344,50 @@ def builtin_targets() -> List[LawTarget]:
     # pins the two paths bit-identical.
     import jax
     if len(jax.devices()) >= 2:
-        try:
-            from ..parallel import collective as _pc
-        except ImportError:
-            _pc = None
-        if _pc is not None:
-            from ..ops.dense import DenseStore
+        from ..ops.dense import DenseStore
+        from ..parallel import collective as _pc
 
-            coll_mesh = _pc.make_collective_mesh(2)
-            coll_step = _pc.make_collective_join(coll_mesh, False, 8)
+        coll_mesh = _pc.make_collective_mesh(2)
+        coll_step = _pc.make_collective_join(coll_mesh, False, 8)
 
-            def coll_fresh():
-                return dense_ops.empty_dense_store(_N)
+        def coll_fresh():
+            return dense_ops.empty_dense_store(_N)
 
-            def coll_gen(rng):
-                return _gen_dense(rng, _N)
+        def coll_gen(rng):
+            return _gen_dense(rng, _N)
 
-            def coll_apply(store, batch):
-                other = DenseStore(
-                    lt=batch["lt"], node=batch["node"],
-                    val=batch["val"],
-                    mod_lt=np.zeros(_N, np.int64),
-                    mod_node=np.zeros(_N, np.int32),
-                    occupied=batch["valid"], tomb=batch["tomb"])
-                stacked, _res = coll_step(
-                    (store, other), np.zeros(2, np.int64),
-                    np.asarray([0, 1], np.int32), np.int64(0))
-                return jax.tree_util.tree_map(lambda a: a[0], stacked)
+        def coll_apply(store, batch):
+            other = DenseStore(
+                lt=batch["lt"], node=batch["node"],
+                val=batch["val"],
+                mod_lt=np.zeros(_N, np.int64),
+                mod_node=np.zeros(_N, np.int32),
+                occupied=batch["valid"], tomb=batch["tomb"])
+            joined, _res = coll_step(
+                (store, other), np.zeros(2, np.int64),
+                np.asarray([0, 1], np.int32), np.int64(0))
+            return joined[0]
 
-            def coll_combine(a, b):
-                # same elementwise lex-max of two wire deltas as
-                # make_wire_join_target: a member store IS a
-                # full-width delta to the group
-                a_newer = ((a["lt"] > b["lt"])
-                           | ((a["lt"] == b["lt"])
-                              & (a["node"] >= b["node"])))
-                a_wins = a["valid"] & (~b["valid"] | a_newer)
-                out = {}
-                for k in ("lt", "node", "val", "tomb", "valid"):
-                    out[k] = np.where(a_wins, a[k], b[k])
-                out["valid"] = a["valid"] | b["valid"]
-                return out
+        def coll_combine(a, b):
+            # same elementwise lex-max of two wire deltas as
+            # make_wire_join_target: a member store IS a
+            # full-width delta to the group
+            a_newer = ((a["lt"] > b["lt"])
+                       | ((a["lt"] == b["lt"])
+                          & (a["node"] >= b["node"])))
+            a_wins = a["valid"] & (~b["valid"] | a_newer)
+            out = {}
+            for k in ("lt", "node", "val", "tomb", "valid"):
+                out[k] = np.where(a_wins, a[k], b[k])
+            out["valid"] = a["valid"] | b["valid"]
+            return out
 
-            targets.append(LawTarget(
-                name="parallel.collective_join[member2]",
-                fresh=coll_fresh, gen=coll_gen, apply=coll_apply,
-                extract=_extract_store, combine=coll_combine,
-                notes="group join as all-reduce over a 2-member mesh; "
-                      "all three laws on member 0's lanes"))
+        targets.append(LawTarget(
+            name="parallel.collective_join[member2]",
+            fresh=coll_fresh, gen=coll_gen, apply=coll_apply,
+            extract=_extract_store, combine=coll_combine,
+            notes="group join as all-reduce over a 2-member mesh; "
+                  "all three laws on member 0's lanes"))
 
     # --- storage plane (docs/STORAGE.md) ---
     #

@@ -209,22 +209,24 @@ class CollectiveGroup:
                   members=len(members), **rid):
             stores = tuple(m._store for m in members)
             args = ((members[0]._sem_device(),) if has_sem else ())
-            stacked, res = step(stores, *args, since, me, canonical_in)
+            new_stores, res = step(stores, *args, since, me,
+                                   canonical_in)
 
             # ONE batched fetch: masks + replicated lanes + clock.
             # mod lanes stay device-only, as everywhere else.
-            win_h, repack_h, lt_h, node_h, val_h, tomb_h, canonical = \
-                jax.device_get((res.win, res.repack, stacked.lt,
-                                stacked.node, stacked.val, stacked.tomb,
-                                res.new_canonical))
+            win_h, repack_h, lanes_h, canonical = jax.device_get(
+                (res.win, res.repack,
+                 [(s.lt, s.node, s.val, s.tomb) for s in new_stores],
+                 res.new_canonical))
+            win_h = win_h.reshape(len(members), -1)
+            repack_h = repack_h.reshape(len(members), -1)
             canonical = int(canonical)
             tree = build_digest_tree(members[0].n_slots, leaf_width,
                                      res.levels)
 
         win_counts = []
         for i, m in enumerate(members):
-            new_store = jax.tree_util.tree_map(lambda a: a[i], stacked)
-            m._store = new_store            # setter clears both caches
+            m._store = new_stores[i]        # setter clears both caches
             m._store_escaped = False
             # Clock lands without a refresh dispatch: the program's
             # canonical IS max(member canonicals, every lt joined).
@@ -235,9 +237,8 @@ class CollectiveGroup:
             m.stats.merges += 1
             win_counts.append(int(win_h[i].sum()))
             if seed_packs:
-                self._seed_pack(m, watermarks[i], repack_h[i], lt_h[i],
-                                node_h[i], val_h[i], tomb_h[i],
-                                canonical, has_sem)
+                self._seed_pack(m, watermarks[i], repack_h[i],
+                                *lanes_h[i], canonical, has_sem)
         return CollectiveJoinReport(
             new_canonical=canonical, win_counts=win_counts,
             digest_root=tree.root, members=len(members))

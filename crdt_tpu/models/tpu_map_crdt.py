@@ -8,9 +8,9 @@ holding the record store as structure-of-arrays lanes twice over:
   decision on the Python-object boundary — recv guard masks
   (vectorized running-max, hlc.dart:80-97), the LWW win compare
   (crdt.dart:83-84), record/JSON export — runs as batched numpy ops
-  here. Rationale: on a remote-proxied accelerator every device→host
-  fetch costs a full round trip that no record-dict batch size
-  amortizes, so a backend that consults the device for win masks or
+  here. Rationale: every device→host fetch costs a full host round
+  trip that no record-dict batch size amortizes, so a backend that
+  consults the device for win masks or
   guard flags is strictly slower than the scalar oracle at every
   record-dict shape. The shadow makes reads and merges fetch-free;
   numpy is the host's SIMD path (the same vectorization story as the

@@ -257,6 +257,13 @@ _NULL_RECORD = _NullRecord()
 _DEFAULT_LEDGER = DispatchLedger()
 
 
+def kernel_label(kernel: str, interpret: bool) -> str:
+    """Ledger name of one Pallas dispatch: interpret-mode runs (the
+    CPU stand-in for Mosaic) carry an ``[interpret]`` suffix, so a
+    chip run's ledger proves no kernel ran interpreted."""
+    return kernel + "[interpret]" if interpret else kernel
+
+
 def default_ledger() -> DispatchLedger:
     """The process-wide ledger every instrumented entry point reports
     to (same singleton discipline as `default_registry`)."""

@@ -466,6 +466,13 @@ TILE = _SB * _LANE  # n_slots must be a multiple of this
 _STREAM_LANE = 1024
 
 
+def mosaic_auto(n_slots: int, platform: str) -> bool:
+    """The ``executor="auto"`` rule every Mosaic path shares (model
+    merges and ingest commits, the sharded fan-in per key shard, the
+    bench): Mosaic lowers on TPU only, over tile-aligned lanes."""
+    return platform == "tpu" and n_slots % TILE == 0
+
+
 def _stream_tile_lane(n: int) -> int:
     return _STREAM_LANE if n % (_SB * _STREAM_LANE) == 0 else _LANE
 
@@ -758,9 +765,9 @@ def _model_fanin_batch_jit(store, cs, canonical_lt, local_node,
                            value_width: int = 64):
     """The model layer's ONE-dispatch merge: wide `DenseStore` +
     `DenseChangeset` in, wide store out — split/convert, the batch
-    kernel, and the re-join all inside a single jit. On remote-proxied
-    backends every separate dispatch costs a host round trip, and an
-    unfused model merge was paying four of them per call.
+    kernel, and the re-join all inside a single jit. Every separate
+    dispatch costs a host round trip, and an unfused model merge was
+    paying four of them per call.
 
     Returns ``(new_store, PallasFaninResult, seen, val_overflow)``
     where ``seen`` counts valid lanes (the stats counter) and
@@ -786,8 +793,9 @@ def model_fanin_batch(store, cs, canonical_lt, local_node, wall_millis,
                       **kw):
     """Ledger-recording host entry for `_model_fanin_batch_jit` (the
     fused model merge; see its docstring for semantics)."""
-    with _obs_device.record("pallas.model_fanin_batch",
-                            dim=cs.valid.shape[0]):
+    name = _obs_device.kernel_label("pallas.model_fanin_batch",
+                                    kw.get("interpret", False))
+    with _obs_device.record(name, dim=cs.valid.shape[0]):
         return _model_fanin_batch_jit(store, cs, canonical_lt,
                                       local_node, wall_millis, **kw)
 
@@ -811,8 +819,8 @@ def _model_fanin_split_jit(store, cs, node_map, canonical_lt,
 
     ``node_map`` (int16[peer_table_len]) rewrites the changeset's
     node ordinals into the local table IN-JIT (each eager dispatch is
-    a host round trip on proxied backends; pass the identity map when
-    tables already match — the gather fuses away to a copy).
+    a host round trip; pass the identity map when tables already
+    match — the gather fuses away to a copy).
 
     Same return contract as `model_fanin_batch`:
     ``(new_store, PallasFaninResult, seen, val_overflow)``. A
@@ -850,8 +858,9 @@ def model_fanin_split(store, cs, node_map, canonical_lt, local_node,
                       wall_millis, **kw):
     """Ledger-recording host entry for `_model_fanin_split_jit` (the
     pre-split fused model merge; see its docstring for semantics)."""
-    with _obs_device.record("pallas.model_fanin_split",
-                            dim=cs.hi.shape[0]):
+    name = _obs_device.kernel_label("pallas.model_fanin_split",
+                                    kw.get("interpret", False))
+    with _obs_device.record(name, dim=cs.hi.shape[0]):
         return _model_fanin_split_jit(store, cs, node_map, canonical_lt,
                                       local_node, wall_millis, **kw)
 
@@ -871,9 +880,9 @@ def _pipelined_model_step_jit(store, cs, canonical, any_bad, overflow,
     model merge (`model_fanin_batch`) plus the window bookkeeping the
     model layer otherwise runs as separate eager ops — flag
     OR-accumulation, first-flag attribution, and the final send bump
-    (`ops.merge.send_step`). On remote-proxied backends each separate
-    dispatch is a host round trip; at the north-star e2e shape the
-    bookkeeping dispatches were costing more than the merge itself.
+    (`ops.merge.send_step`). Each separate dispatch is a host round
+    trip; at the north-star e2e shape the bookkeeping dispatches were
+    costing more than the merge itself.
 
     ``wall_merge`` is the absorption-phase wall read, ``wall_send``
     the send bump's — two reads, like the unfused path, so injected
@@ -892,8 +901,9 @@ def _pipelined_model_step_jit(store, cs, canonical, any_bad, overflow,
 def pipelined_model_step(store, cs, *args, **kw):
     """Ledger-recording host entry for `_pipelined_model_step_jit`
     (the coarse pipelined merge; see its docstring for semantics)."""
-    with _obs_device.record("pallas.pipelined_model_step",
-                            dim=cs.valid.shape[0]):
+    name = _obs_device.kernel_label("pallas.pipelined_model_step",
+                                    kw.get("interpret", False))
+    with _obs_device.record(name, dim=cs.valid.shape[0]):
         return _pipelined_model_step_jit(store, cs, *args, **kw)
 
 
@@ -926,8 +936,9 @@ def pipelined_model_step_split(store, cs, *args, **kw):
     """Ledger-recording host entry for
     `_pipelined_model_step_split_jit` (the pre-split coarse pipelined
     merge; see its docstring for semantics)."""
-    with _obs_device.record("pallas.pipelined_model_step_split",
-                            dim=cs.hi.shape[0]):
+    name = _obs_device.kernel_label("pallas.pipelined_model_step_split",
+                                    kw.get("interpret", False))
+    with _obs_device.record(name, dim=cs.hi.shape[0]):
         return _pipelined_model_step_split_jit(store, cs, *args, **kw)
 
 

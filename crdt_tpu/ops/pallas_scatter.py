@@ -121,9 +121,14 @@ def _scatter_step(store: DenseStore, tile_ids, valid, lt_d, val_d,
     padded_t = valid.shape[0] // _SB
     # Index maps see (grid index, *scalar prefetch operands): store
     # blocks follow the prefetched tile ids, update blocks walk 0..t.
-    st_spec = pl.BlockSpec((_SB, _LANE), lambda i, ids, me: (ids[i], 0),
+    # Block indices must be int32: a bare ``0`` traces as int64 under
+    # x64 and Mosaic refuses the index map.
+    _i32 = jnp.int32
+    st_spec = pl.BlockSpec((_SB, _LANE),
+                           lambda i, ids, me: (ids[i], _i32(0)),
                            memory_space=pltpu.VMEM)
-    up_spec = pl.BlockSpec((_SB, _LANE), lambda i, ids, me: (i, 0),
+    up_spec = pl.BlockSpec((_SB, _LANE),
+                           lambda i, ids, me: (i, _i32(0)),
                            memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=(padded_t,),
@@ -161,8 +166,9 @@ def ingest_scatter_tiles(store: DenseStore, slots: np.ndarray,
     tile_ids, valid, lt_d, val_d, tomb_d = prepare_tile_updates(
         np.asarray(slots, np.int64), np.asarray(lt, np.int64),
         np.asarray(val, np.int64), np.asarray(tomb), store.lt.shape[0])
-    with _obs_device.record("pallas.ingest_scatter_tiles",
-                            dim=int(tile_ids.shape[0]),
+    name = _obs_device.kernel_label("pallas.ingest_scatter_tiles",
+                                    interpret)
+    with _obs_device.record(name, dim=int(tile_ids.shape[0]),
                             donated=store.lt if donate else None):
         return _scatter_jit(donate, interpret)(
             store, jnp.asarray(tile_ids), jnp.asarray(valid),

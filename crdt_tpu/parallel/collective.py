@@ -55,7 +55,8 @@ from ..ops.digest import (fold_leaves, slot_digests,
 from ..semantics.kernels import (ORSET_UNIVERSE, SEM_GCOUNTER,
                                  SEM_MVREG, SEM_ORSET, SEM_PNCOUNTER,
                                  _PN_HALF, _mvreg_union)
-from .fanin import Mesh, P, _BIG_RANK, _make_mesh, _shard_map
+from .fanin import (Mesh, NamedSharding, P, _BIG_RANK, _make_mesh,
+                    _shard_map, pmax_i64)
 
 #: The 1-D replica-group axis. Distinct from fanin's REPLICA_AXIS on
 #: purpose: a fan-in mesh shards *changeset rows*; a collective mesh
@@ -65,8 +66,8 @@ MEMBER_AXIS = "member"
 
 class CollectiveJoinResult(NamedTuple):
     new_canonical: jax.Array        # int64 scalar, replicated
-    win: jax.Array                  # bool[G, N] per-member changed mask
-    repack: jax.Array               # bool[G, N] mod_lt >= since mask
+    win: jax.Array                  # bool[G*N] per-member changed mask
+    repack: jax.Array               # bool[G*N] mod_lt >= since mask
     levels: Tuple[jax.Array, ...]   # post-join digest levels, root-first
 
 
@@ -85,14 +86,16 @@ def _typed_group_val(sem, occ, l_lt, l_val, m1, w_val):
     the absent-member identity, which is exactly what lets it ride
     collectives instead of a fold."""
     ax = MEMBER_AXIS
-    gmax = jax.lax.pmax(l_val, ax)
-    pos = jax.lax.pmax((l_val >> 32) & _PN_HALF, ax)
-    neg = jax.lax.pmax(l_val & _PN_HALF, ax)
-    pn = (pos << 32) | neg
+    gmax = pmax_i64(l_val, ax)
+    # The 31-bit halves and the nibbles fit int32, the width a TPU
+    # max all-reduce takes.
+    pos = jax.lax.pmax(((l_val >> 32) & _PN_HALF).astype(jnp.int32), ax)
+    neg = jax.lax.pmax((l_val & _PN_HALF).astype(jnp.int32), ax)
+    pn = (pos.astype(jnp.int64) << 32) | neg.astype(jnp.int64)
     # orset: all 16 causal-length nibbles in ONE stacked pmax.
-    nibs = jnp.stack([(l_val >> (4 * i)) & 0xF
+    nibs = jnp.stack([((l_val >> (4 * i)) & 0xF).astype(jnp.int32)
                       for i in range(ORSET_UNIVERSE)])
-    g_nibs = jax.lax.pmax(nibs, ax)
+    g_nibs = jax.lax.pmax(nibs, ax).astype(jnp.int64)
     ors = jnp.zeros_like(l_val)
     for i in range(ORSET_UNIVERSE):
         ors = ors | (g_nibs[i] << (4 * i))
@@ -115,17 +118,17 @@ def _typed_group_val(sem, occ, l_lt, l_val, m1, w_val):
 
 def _join_block(leaf_width: int, has_sem: bool, store: DenseStore,
                 *rest):
-    """Per-member body under shard_map: lanes arrive [1, N], scalars
-    per member arrive [1]; ``sem`` (when present) and ``canonical_in``
-    are replicated."""
+    """Per-member body under shard_map: lanes arrive as the member's
+    own [N] block of the [G*N] group lanes, scalars per member arrive
+    [1]; ``sem`` (when present) and ``canonical_in`` are replicated."""
     if has_sem:
         sem, since, me, canonical_in = rest
     else:
         since, me, canonical_in = rest
         sem = None
-    lt, node, val = store.lt[0], store.node[0], store.val[0]
-    occ, tomb = store.occupied[0], store.tomb[0]
-    mod_lt, mod_node = store.mod_lt[0], store.mod_node[0]
+    lt, node, val = store.lt, store.node, store.val
+    occ, tomb = store.occupied, store.tomb
+    mod_lt, mod_node = store.mod_lt, store.mod_node
     since_i, me_i = since[0], me[0]
 
     # Mask absent slots to the join identities so an unoccupied member
@@ -138,7 +141,7 @@ def _join_block(leaf_width: int, has_sem: bool, store: DenseStore,
     # Lexicographic (lt, node) max over the group — the fanin block's
     # collective composition, verbatim.
     ax = MEMBER_AXIS
-    m1 = jax.lax.pmax(l_lt, ax)
+    m1 = pmax_i64(l_lt, ax)
     node_cand = jnp.where(l_lt == m1, l_node, _I32_NEG)
     m2 = jax.lax.pmax(node_cand, ax)
     has = (l_lt == m1) & (l_node == m2)
@@ -171,7 +174,7 @@ def _join_block(leaf_width: int, has_sem: bool, store: DenseStore,
 
     new_canonical = jnp.maximum(
         canonical_in,
-        jax.lax.pmax(jnp.max(jnp.where(occ, lt, _NEG)), ax))
+        pmax_i64(jnp.max(jnp.where(occ, lt, _NEG)), ax))
     mod_lt_out = jnp.where(win, new_canonical, mod_lt)
     mod_node_out = jnp.where(win, me_i, mod_node)
     repack = occ_out & (mod_lt_out >= since_i)
@@ -184,10 +187,33 @@ def _join_block(leaf_width: int, has_sem: bool, store: DenseStore,
     leaves = fold_leaves(h, leaf_width)
 
     new_store = DenseStore(
-        lt=lt_out[None], node=node_out[None], val=val_out[None],
-        mod_lt=mod_lt_out[None], mod_node=mod_node_out[None],
-        occupied=occ_out[None], tomb=tomb_out[None])
-    return (new_store, win[None], repack[None], new_canonical, leaves)
+        lt=lt_out, node=node_out, val=val_out, mod_lt=mod_lt_out,
+        mod_node=mod_node_out, occupied=occ_out, tomb=tomb_out)
+    return (new_store, win, repack, new_canonical, leaves)
+
+
+def _group_lanes(stores, mesh: Mesh) -> DenseStore:
+    """The members' stores as [G*N] lanes sharded over the member
+    axis, member ``i``'s block on the mesh's ``i``-th device. A member
+    already living there moves nothing; one elsewhere (a fresh replica
+    on the default device) is copied to its device once."""
+    devices = list(mesh.devices.flat)
+    sharding = NamedSharding(mesh, P(MEMBER_AXIS))
+    lanes = []
+    for f in range(len(DenseStore._fields)):
+        rows = [jax.device_put(s[f], d) for s, d in zip(stores, devices)]
+        lanes.append(jax.make_array_from_single_device_arrays(
+            (len(rows) * rows[0].shape[0],), sharding, rows))
+    return DenseStore(*lanes)
+
+
+def _member_blocks(lane: jax.Array) -> list:
+    """Split a member-sharded [G*N] lane into its G per-device [N]
+    blocks, in member order — zero-copy: each block stays on the
+    device that computed it."""
+    shards = sorted(lane.addressable_shards,
+                    key=lambda sh: sh.index[0].start or 0)
+    return [sh.data for sh in shards]
 
 
 @functools.lru_cache(maxsize=None)
@@ -197,15 +223,19 @@ def make_collective_join(mesh: Mesh, has_sem: bool, leaf_width: int,
     mesh.
 
     Returns ``step(stores, [sem,] since, me, canonical_in) ->
-    (stacked_store, CollectiveJoinResult)`` where ``stores`` is a
+    (member_stores, CollectiveJoinResult)`` where ``stores`` is a
     G-tuple of per-member `DenseStore`s (G = mesh extent), ``sem`` is
     the shared [N] int8 tag column (only when ``has_sem``), ``since``
     and ``me`` are [G] per-member watermark lts / node ordinals, and
     ``canonical_in`` is the max of the members' pre-join canonical
-    lts. The returned store is stacked [G, N]; replicated lanes are
-    identical across members, ``mod`` lanes are per-member.
+    lts. Member ``i``'s store joins on, and comes back on, the mesh's
+    ``i``-th device: one whole replica per device. Replicated lanes
+    are identical across members, ``mod`` lanes are per-member.
     ``donate=True`` consumes the input store buffers (gate it off on
-    CPU, where XLA ignores donation with a warning)."""
+    CPU, where XLA ignores donation with a warning).
+
+    ``step.jitted`` is the program itself, over [G*N] member-sharded
+    lanes (`_group_lanes`)."""
     g = mesh.shape[MEMBER_AXIS]
     store_spec = DenseStore(*([P(MEMBER_AXIS)]
                               * len(DenseStore._fields)))
@@ -219,10 +249,8 @@ def make_collective_join(mesh: Mesh, has_sem: bool, leaf_width: int,
                    P(), P()),
         check_vma=False)
 
-    def _step(stores, *args):
-        stacked = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *stores)
-        out_store, win, repack, canonical, leaves = join(stacked, *args)
+    def _step(group, *args):
+        out_store, win, repack, canonical, leaves = join(group, *args)
         return out_store, CollectiveJoinResult(
             new_canonical=canonical, win=win, repack=repack,
             levels=tree_levels_from_leaves(leaves))
@@ -234,11 +262,13 @@ def make_collective_join(mesh: Mesh, has_sem: bool, leaf_width: int,
             raise ValueError(
                 f"collective join over a {g}-member mesh got "
                 f"{len(stores)} stores")
+        group = _group_lanes(stores, mesh)
         with _obs_device.record("parallel.collective_join",
                                 dim=stores[0].lt.shape[0],
-                                donated=(stores[0].lt if donate
-                                         else None)):
-            return jitted(stores, *args)
+                                donated=group.lt if donate else None):
+            out, res = jitted(group, *args)
+        blocks = [_member_blocks(lane) for lane in out]
+        return tuple(DenseStore(*lanes) for lanes in zip(*blocks)), res
 
     # The raw jitted program, for jaxpr tracing (analysis/jaxpr_audit)
     # without the ledger accounting a real dispatch carries.

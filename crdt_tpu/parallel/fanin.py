@@ -53,22 +53,9 @@ _obs_device.register(
     "parallel.sharded_ingest", "parallel.sharded_digest",
     "parallel.sharded_delta_mask", "parallel.sharded_max_logical_time",
     "parallel.sharded_compact")
-try:                                     # jax >= 0.5 re-exports P
-    from jax import P
-except ImportError:                      # pragma: no cover
-    from jax.sharding import PartitionSpec as P
+from jax import P
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding
-
-try:                                     # jax >= 0.6: top-level export
-    _shard_map = jax.shard_map
-except AttributeError:                   # pragma: no cover
-    # Older jax ships it under experimental with the replication
-    # check named check_rep (same semantics as check_vma).
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=check_vma)
 
 from ..hlc import MAX_COUNTER, MAX_DRIFT, SHIFT
 from ..ops.dense import (DenseChangeset, DenseStore, reduce_replicas,
@@ -87,6 +74,22 @@ SLICE_AXIS = "slice"
 # initialize the jax backend at import time, foreclosing the platform
 # selection entry points need to do first.
 _BIG_RANK = 2 ** 30
+
+
+def pmax_i64(x: jax.Array, axes) -> jax.Array:
+    """``jax.lax.pmax`` of an int64 array as two int32 all-reduces: the
+    TPU lowers only the sum of a 64-bit all-reduce. The hi words
+    reduce first; the lo words (biased so that signed int32 order is
+    their unsigned order) reduce masked to the members holding the
+    winning hi word. Bit-identical to the int64 pmax."""
+    hi = (x >> 32).astype(jnp.int32)
+    bias = jnp.uint32(0x80000000)
+    lo = jax.lax.bitcast_convert_type(x.astype(jnp.uint32) ^ bias,
+                                      jnp.int32)
+    g_hi = jax.lax.pmax(hi, axes)
+    g_lo = jax.lax.pmax(jnp.where(hi == g_hi, lo, _I32_NEG), axes)
+    lo_u = jax.lax.bitcast_convert_type(g_lo, jnp.uint32) ^ bias
+    return (g_hi.astype(jnp.int64) << 32) | lo_u.astype(jnp.int64)
 
 
 class ShardedFaninResult(NamedTuple):
@@ -206,7 +209,7 @@ def _fanin_block(replica_axes: tuple, store: DenseStore,
     # --- cross-device lexicographic (lt, node) max over the replica
     # axes: pmax lt → masked pmax node → stable pmin rank → one-hot psum
     # of the winner's payload lanes. ICI within a slice, DCN across. ---
-    m1 = jax.lax.pmax(best_lt, replica_axes)
+    m1 = pmax_i64(best_lt, replica_axes)
     node_cand = jnp.where(best_lt == m1, best_node, _I32_NEG)
     m2 = jax.lax.pmax(node_cand, replica_axes)
     has = (best_lt == m1) & (best_node == m2)
@@ -222,7 +225,7 @@ def _fanin_block(replica_axes: tuple, store: DenseStore,
     # --- canonical absorption: global max over every record seen ---
     new_canonical = jnp.maximum(
         canonical_lt,
-        jax.lax.pmax(jnp.max(jnp.where(g_any, m1, _NEG)), all_axes))
+        pmax_i64(jnp.max(jnp.where(g_any, m1, _NEG)), all_axes))
 
     # --- LWW vs the local key shard (strict: local wins exact ties,
     # crdt.dart:84). Identical on every device of a key column, so the
@@ -252,11 +255,7 @@ def _flat_rank(replica_axes: tuple) -> jax.Array:
     flat rank is the earliest replica row (sequential-merge parity)."""
     rank = jax.lax.axis_index(replica_axes[0])
     for a in replica_axes[1:]:
-        # psum(1, axis) is the portable axis size (jax.lax.axis_size
-        # only exists on newer jax); it folds to a constant in-trace.
-        size = (jax.lax.axis_size(a) if hasattr(jax.lax, "axis_size")
-                else jax.lax.psum(1, a))
-        rank = rank * size + jax.lax.axis_index(a)
+        rank = rank * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return rank
 
 
@@ -298,7 +297,7 @@ def _pallas_fanin_block(replica_axes: tuple, chunk_rows: int,
     local_max = jnp.max(masked_lt)
     m_loc = jnp.max(jnp.where(cs.valid & (cs.node == local_node),
                               cs.lt, _NEG))
-    g = jax.lax.pmax(jnp.stack([local_max, m_loc]), all_axes)
+    g = pmax_i64(jnp.stack([local_max, m_loc]), all_axes)
     g_max, g_loc = g[0], g[1]
     new_canonical = jnp.maximum(canonical_lt, g_max)
     any_dup = g_loc > canonical_lt
@@ -322,7 +321,7 @@ def _pallas_fanin_block(replica_axes: tuple, chunk_rows: int,
     # rows — sequential-merge parity, matching the in-kernel strict
     # compare that keeps the earliest row). ---
     p_lt = jnp.where(partial.occupied, partial.lt, _NEG)
-    m1 = jax.lax.pmax(p_lt, replica_axes)
+    m1 = pmax_i64(p_lt, replica_axes)
     node_cand = jnp.where(p_lt == m1, partial.node, _I32_NEG)
     m2 = jax.lax.pmax(node_cand, replica_axes)
     has = (p_lt == m1) & (partial.node == m2)
@@ -388,7 +387,8 @@ def make_sharded_pallas_fanin(mesh: Mesh, *, chunk_rows: int = 8,
         ),
         check_vma=False,
     )
-    return _record_step("parallel.sharded_pallas_fanin", jax.jit(step))
+    return _record_step(_obs_device.kernel_label(
+        "parallel.sharded_pallas_fanin", interpret), jax.jit(step))
 
 
 def _record_step(kernel: str, jitted, *, donated_store: bool = False,
@@ -624,7 +624,7 @@ def sharded_max_logical_time(mesh: Mesh):
 
     def _max(store: DenseStore) -> jax.Array:
         local = jnp.max(jnp.where(store.occupied, store.lt, 0))
-        return jax.lax.pmax(local, mesh.axis_names)
+        return pmax_i64(local, mesh.axis_names)
 
     return _record_step(
         "parallel.sharded_max_logical_time",

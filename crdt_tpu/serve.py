@@ -122,7 +122,9 @@ def frame_pieces(bufs, codec: Optional[FrameCodec] = None,
     ``writer.writelines`` — the async twin of `send_bytes_frame`,
     sharing its codec/tally/size-cap semantics. Pieces pass through
     as memoryviews (a packed delta's arena views reach the transport
-    with zero copies in our code)."""
+    with zero copies in our code). Zero-length pieces are dropped:
+    CPython 3.12's vectored transport write spins forever on a
+    ``sendmsg`` that returns 0 for an all-empty tail."""
     if codec is not None:
         bufs = codec.encode(bufs, tally)
     views = _flat_views(bufs)
@@ -132,7 +134,7 @@ def frame_pieces(bufs, codec: Optional[FrameCodec] = None,
                          f"MAX_FRAME_BYTES ({MAX_FRAME_BYTES})")
     if tally is not None:
         tally.sent += 4 + total
-    return [struct.pack(">I", total)] + views
+    return [struct.pack(">I", total)] + [v for v in views if v.nbytes]
 
 
 async def write_frame_async(writer: asyncio.StreamWriter, bufs,

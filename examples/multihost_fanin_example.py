@@ -30,18 +30,10 @@ ROWS = 8          # replica rows, fanned in across the replica axis
 
 
 def worker(process_id: int) -> None:
-    # 2 local devices × 2 procs; the env flag must be set before jax
-    # initializes its backends, and older jax lacks the config option
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=2").strip()
+    # 2 local devices × 2 procs, set before jax initializes a backend.
     import jax
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 2)
-    except AttributeError:
-        pass                      # older jax: the XLA flag covers it
+    jax.config.update("jax_num_cpu_devices", 2)
     jax.distributed.initialize(
         coordinator_address=f"127.0.0.1:{os.environ['MH_EXAMPLE_PORT']}",
         num_processes=2, process_id=process_id)
@@ -99,14 +91,6 @@ def worker(process_id: int) -> None:
 def main() -> None:
     if "MH_EXAMPLE_RANK" in os.environ:
         worker(int(os.environ["MH_EXAMPLE_RANK"]))
-        return
-    import jax
-    if tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5):
-        # the CPU backend grew multi-process collectives in 0.5; on
-        # older jax every cross-process device_put raises
-        # "Multiprocess computations aren't implemented"
-        print(f"skipped: jax {jax.__version__} cannot run "
-              "multi-process CPU collectives (needs jax >= 0.5)")
         return
     # Fresh ephemeral coordinator port per run: concurrent suites on
     # one host must not collide. (The tiny bind/close race window is

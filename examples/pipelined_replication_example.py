@@ -4,8 +4,8 @@ zero per-merge host synchronization.
 The scenario: a dense replica ingesting deltas from many peers in a
 tight loop — the steady-state of an anti-entropy mesh. Unpipelined,
 every `merge` fetches its guard flags and canonical clock from the
-device (a full host↔device round trip per call — the dominant cost on
-remote-proxied accelerators). Inside a `DenseCrdt.pipelined()` window
+device (a full host↔device round trip per call — the dominant cost of
+a small merge). Inside a `DenseCrdt.pipelined()` window
 the canonical clock threads as a device scalar, guard flags
 accumulate, and ONE readback at the window's end settles everything.
 

@@ -1,0 +1,153 @@
+"""Compile-only rehearsal of the served path's device programs for a
+described (not attached) TPU v5e, at the widths `chip_smoke.py` runs:
+a 16M-slot store, 8-row changesets, a 64M-slot store key-sharded over
+a 2x2 mesh. Nothing executes; the chip's compiler refuses what it
+would refuse on the chip (misaligned blocks, int64 block indices,
+64-bit max all-reduces, programs past HBM).
+
+The topology is described only inside the module fixture below —
+never at import — so every xdist worker collects the same tests and
+only the worker running this file loads the TPU compiler.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from crdt_tpu.ops import pallas_merge as pm
+from crdt_tpu.ops.dense import (DenseChangeset, DenseStore,
+                                _compact_remap_jit, _gc_purge_jit)
+
+N = 1 << 24          # chip_smoke.py's store
+R = 8                # its merge_many peer rows
+TILES = 64           # touched tiles in one ingest flush
+HBM_BYTES = 16 * 10 ** 9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _store(n, sharding):
+    return DenseStore(*(_sds((n,), d, sharding) for d in (
+        jnp.int64, jnp.int32, jnp.int64, jnp.int64, jnp.int32,
+        jnp.bool_, jnp.bool_)))
+
+
+def _changeset(r, n, sharding):
+    return DenseChangeset(*(_sds((r, n), d, sharding) for d in (
+        jnp.int64, jnp.int32, jnp.int64, jnp.bool_, jnp.bool_)))
+
+
+def _compile(fn, *args, kernel=True):
+    compiled = jax.jit(fn).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes
+            ) < HBM_BYTES
+    if kernel:
+        assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _scalars(sharding):
+    return (_sds((), jnp.int64, sharding), _sds((), jnp.int32, sharding),
+            _sds((), jnp.int64, sharding))
+
+
+def test_model_fanin_batch_compiles(one_chip):
+    # DenseCrdt._dispatch_pallas: the one fused merge dispatch.
+    _compile(lambda *a: pm.model_fanin_batch.__wrapped__(
+        *a, chunk_rows=R), _store(N, one_chip),
+        _changeset(R, N, one_chip), *_scalars(one_chip))
+
+
+@pytest.mark.parametrize("kind", ["batch", "stream"])
+def test_split_fanin_kernels_compile(one_chip, kind):
+    sst = pm.SplitStore(*(_sds((N,), d, one_chip) for d in (
+        jnp.int32, jnp.uint32, jnp.int32, jnp.int32, jnp.uint32,
+        jnp.int32, jnp.int32, jnp.uint32, jnp.int32)))
+    scs = pm.SplitChangeset(*(_sds((R, N), d, one_chip) for d in (
+        jnp.int32, jnp.uint32, jnp.int32, jnp.int32, jnp.uint32,
+        jnp.int32)))
+    if kind == "batch":
+        fn = lambda *a: pm.pallas_fanin_batch.__wrapped__(  # noqa: E731
+            *a, chunk_rows=R)
+    else:
+        fn = lambda *a: pm.pallas_fanin_stream.__wrapped__(  # noqa: E731
+            *a, n_chunks=4)
+    _compile(fn, sst, scs, *_scalars(one_chip))
+
+
+def test_ingest_scatter_tiles_compiles(one_chip):
+    from crdt_tpu.ops.pallas_scatter import _scatter_step
+    rows = TILES * pm._SB
+    lanes = [_sds((rows, pm._LANE), d, one_chip) for d in (
+        jnp.int32, jnp.int64, jnp.int64, jnp.bool_)]
+    _compile(lambda *a: _scatter_step(*a, interpret=False),
+             _store(N, one_chip), _sds((TILES,), jnp.int32, one_chip),
+             *lanes, _sds((1,), jnp.int32, one_chip))
+
+
+def test_gc_purge_compiles(one_chip):
+    _compile(_gc_purge_jit(True, None), _store(N, one_chip),
+             _sds((), jnp.int64, one_chip), kernel=False)
+
+
+def test_compact_remap_compiles(one_chip):
+    from crdt_tpu.models.dense_crdt import DenseCrdt
+    span = _sds((1,), jnp.int64, one_chip)
+    _compile(_compact_remap_jit(True, DenseCrdt.DIGEST_LEAF_WIDTH, False,
+                                None),
+             _store(N, one_chip), span, span, kernel=False)
+
+
+def test_sharded_pallas_fanin_compiles_on_2x2(topo):
+    from crdt_tpu.parallel import (KEY_AXIS, REPLICA_AXIS,
+                                   changeset_sharding,
+                                   make_sharded_pallas_fanin,
+                                   store_sharding)
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4),
+                (REPLICA_AXIS, KEY_AXIS))
+    step = make_sharded_pallas_fanin(mesh, chunk_rows=R)
+    compiled = _compile(step.__wrapped__,
+                        _store(4 * N, store_sharding(mesh)),
+                        _changeset(R, 4 * N, changeset_sharding(mesh)),
+                        *_scalars(NamedSharding(mesh, P())))
+    assert "all-reduce" in compiled.as_text()
+
+
+def test_collective_join_compiles_on_2x2(topo):
+    from crdt_tpu.parallel.collective import (MEMBER_AXIS,
+                                              make_collective_join)
+    from crdt_tpu.models.dense_crdt import DenseCrdt
+    mesh = Mesh(np.asarray(topo.devices), (MEMBER_AXIS,))
+    step = make_collective_join(mesh, False, DenseCrdt.DIGEST_LEAF_WIDTH,
+                                donate=True)
+    rep, mem = NamedSharding(mesh, P()), NamedSharding(mesh,
+                                                       P(MEMBER_AXIS))
+    # One whole 16M-slot member per chip: [4*N] lanes over the members.
+    _compile(step.jitted, _store(4 * N, mem), _sds((4,), jnp.int64, mem),
+             _sds((4,), jnp.int32, mem), _sds((), jnp.int64, rep),
+             kernel=False)

@@ -297,6 +297,29 @@ def test_packed_round_through_tier_converges_both_ways():
     assert served.get(1) == 10 and served.get(2) == 20
 
 
+def test_empty_packed_delta_does_not_wedge_stop():
+    # An empty delta's frame carries zero-length lane views; handed to
+    # CPython 3.12's vectored writelines they made sendmsg return 0
+    # forever and stop() time out behind the wedged transport.
+    served = DenseCrdt("a", n_slots=64)
+    client = DenseCrdt("b", n_slots=64)
+    tier = ServeTier(served).start()
+    try:
+        with PeerConnection(tier.host, tier.port, timeout=5.0) as conn:
+            mark = sync_packed_over_conn(client, conn, since=None)
+            sync_packed_over_conn(client, conn, since=mark)
+    finally:
+        t0 = time.monotonic()
+        tier.stop()
+    assert time.monotonic() - t0 < 5.0
+
+
+def test_frame_pieces_drop_zero_length_views():
+    from crdt_tpu.serve import frame_pieces
+    pieces = frame_pieces([b"ab", memoryview(b""), np.zeros(0, np.int64)])
+    assert [bytes(p) for p in pieces] == [b"\x00\x00\x00\x02", b"ab"]
+
+
 def test_writes_landed_mid_session_reach_packed_pulls():
     served = DenseCrdt("a", n_slots=64)
     client = DenseCrdt("b", n_slots=64)
