@@ -1345,10 +1345,10 @@ def bench_serve(sessions: int = 10000, rate_hz: float = 1.0,
         ticks0 = ticks_c.value(trigger="tick", node="srv")
 
         # Ack attribution: the tier decomposes every acked write into
-        # queue_wait / stamp / scatter / ack_write phase observations
-        # (crdt_tpu_serve_ack_phase_seconds); the per-phase histogram
-        # SUM deltas across the measured run must reconstruct the ack
-        # histogram's sum delta — the 10% acceptance bound from the
+        # queue_wait / replica_wait / stamp / scatter / ack_write phase
+        # observations (crdt_tpu_serve_ack_phase_seconds); the per-phase
+        # histogram SUM deltas across the measured run must reconstruct
+        # the ack histogram's sum delta — the 10% acceptance bound from the
         # PR 11 issue. Deltas, not absolutes: the jit warm loop and
         # the single-session yardstick above already observed.
         def _hist_sums(h, key=None):

@@ -26,6 +26,7 @@ The columnar store round-trips through `crdt_tpu.checkpoint.save_dense`.
 
 from __future__ import annotations
 
+import time
 from collections import OrderedDict
 from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -199,6 +200,10 @@ class DenseCrdt:
         self._pipe: Optional[_PipeState] = None
         # Active ingest() write combiner, or None (models/ingest.py).
         self._ingest = None
+        # Seconds point reads (`get`) have waited for device work
+        # already queued on the store: a running total, read as a delta
+        # (the serving tier's `store_wait` read phase).
+        self.store_wait_seconds = 0.0
         self._pending_val_overflow = None
         # Per-slot semantics tags (`crdt_tpu.semantics`, docs/TYPES.md):
         # None == every slot is LWW (tag 0), the seed behavior. The
@@ -251,6 +256,13 @@ class DenseCrdt:
         self.drain_ingest()
         self._store_escaped = True
         return self._store
+
+    def wait_store(self) -> None:
+        """Block until device work already queued on the store's lanes
+        (earlier commits and merges) has finished. Unlike `store`, it
+        neither drains the ingest window nor marks the store escaped,
+        so later commits still donate the lanes."""
+        jax.block_until_ready(self._store)
 
     @property
     def store_generation(self) -> int:
@@ -609,11 +621,18 @@ class DenseCrdt:
             staged, v = self._ingest.pending_value(slot)
             if staged:
                 return v
+        st = self._store
+        scalars = (st.occupied[slot], st.tomb[slot], st.val[slot])
+        # The slices queue on the device behind its earlier work on
+        # the store (commits, merges); that wait is counted apart from
+        # the read's own fetch, after the dispatch so the device runs
+        # the slices straight after it.
+        t = time.perf_counter()
+        self.wait_store()
+        self.store_wait_seconds += time.perf_counter() - t
         # One batched fetch: three sequential scalar reads pay three
         # device→host round trips.
-        occ, tomb, val = jax.device_get(
-            (self._store.occupied[slot], self._store.tomb[slot],
-             self._store.val[slot]))
+        occ, tomb, val = jax.device_get(scalars)
         return int(val) if bool(occ) and not bool(tomb) else None
 
     def get_slot_record(self, slot: int) -> Optional[Record]:
@@ -2396,14 +2415,16 @@ class DenseCrdt:
         # The small result scalars come back in ONE batched fetch: each
         # separate readback is a full host round trip. The [N] win
         # mask stays on device unless a watch subscriber needs it.
-        if voverflow is None:
-            any_bad, win_count, new_canonical = jax.device_get(
-                (res.any_bad, res.win_count, res.new_canonical))
-            val_ovf = False
-        else:
-            any_bad, win_count, new_canonical, val_ovf = jax.device_get(
-                (res.any_bad, res.win_count, res.new_canonical,
-                 voverflow))
+        from ..obs.trace import span
+        with span("merge.guard_fetch", kind="merge"):
+            if voverflow is None:
+                any_bad, win_count, new_canonical = jax.device_get(
+                    (res.any_bad, res.win_count, res.new_canonical))
+                val_ovf = False
+            else:
+                any_bad, win_count, new_canonical, val_ovf = \
+                    jax.device_get((res.any_bad, res.win_count,
+                                    res.new_canonical, voverflow))
         if bool(val_ovf):
             # Raised BEFORE the store swap: the merge is rejected
             # whole (replica untouched; the offending records were

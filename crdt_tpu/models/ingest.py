@@ -90,7 +90,7 @@ class WriteCombiner:
 
     __slots__ = ("_owner", "_auto", "_slots", "_vals", "_tombs",
                  "_group", "_k", "_groups", "_pending", "flushes",
-                 "rows_committed", "on_flush", "last_phase_seconds")
+                 "rows_committed", "last_phase_seconds")
 
     def __init__(self, owner: "DenseCrdt",
                  auto_flush_rows: int = 1 << 16):
@@ -111,13 +111,6 @@ class WriteCombiner:
         self._pending: dict = {}
         self.flushes = 0
         self.rows_committed = 0
-        # Optional flush listener ``(trigger, rows, seconds)``, fired
-        # after EVERY successful commit whatever its trigger (tick,
-        # auto, barrier) — how the serving tier observes its true
-        # flush-latency distribution without wrapping every drain
-        # site. Listener errors are swallowed: observability must
-        # never fail a commit.
-        self.on_flush = None
         # Per-phase wall time of the LAST flush: {"stamp": s,
         # "scatter": s} — the HLC stamp leg (wall read + counter run)
         # vs the dedup + device-scatter dispatch leg. The serving tier
@@ -274,12 +267,6 @@ class WriteCombiner:
         rows_c.inc(d, node=node)
         groups_c.inc(groups, node=node)
         seconds_h.observe(dt, node=node)
-        cb = self.on_flush
-        if cb is not None:
-            try:
-                cb(trigger, d, dt)
-            except Exception:
-                pass
         return True
 
     def _emit_commit(self, slots: np.ndarray, vals: np.ndarray,

@@ -43,7 +43,10 @@ Exposition (all on the default `MetricsRegistry`, so they ride the
 The recording fast path is a class-based context manager (two
 ``perf_counter`` reads, one dict update under the ledger lock, one
 counter inc, one histogram observe — single-digit microseconds against
-dispatch costs of 100 µs+). ``default_ledger().enabled = False``
+dispatch costs of 100 µs+). It also opens a
+``jax.profiler.TraceAnnotation`` named after the kernel, so every
+ledger dispatch appears under its own name in a profile, beside the
+device operations it enqueues. ``default_ledger().enabled = False``
 short-circuits ``record()`` to a shared no-op so the bench suite can
 measure the ledger's own overhead differentially
 (``ledger_overhead_frac`` in ``bench.py --mode ingest/--mode sync``,
@@ -59,6 +62,8 @@ from __future__ import annotations
 
 import time
 from typing import Dict, Optional
+
+import jax.profiler
 
 from ..analysis.concurrency import make_lock
 from .registry import MetricsRegistry, default_registry
@@ -221,7 +226,8 @@ class DispatchLedger:
 
 
 class _Record:
-    __slots__ = ("_ledger", "_kernel", "_dim", "_donated", "_t0")
+    __slots__ = ("_ledger", "_kernel", "_dim", "_donated", "_t0",
+                 "_annotation")
 
     def __init__(self, ledger: DispatchLedger, kernel: str,
                  dim: Optional[int], donated):
@@ -231,10 +237,13 @@ class _Record:
         self._donated = donated
 
     def __enter__(self):
+        self._annotation = jax.profiler.TraceAnnotation(self._kernel)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        self._annotation.__exit__(exc_type, exc, tb)
         if exc_type is None:
             self._ledger._dispatch(self._kernel,
                                    time.perf_counter() - self._t0,

@@ -42,6 +42,7 @@ from .dense import DenseStore
 from .pallas_merge import (_LANE, _SB, TILE, SplitStore, _join64, _split64,
                            join_store, split_store)
 from ..obs import device as _obs_device
+from ..obs.trace import span
 
 _obs_device.register("pallas.ingest_scatter_tiles")
 
@@ -163,9 +164,11 @@ def ingest_scatter_tiles(store: DenseStore, slots: np.ndarray,
     """Commit a deduped ingest batch through the touched-tile kernel.
     Bit-identical to `ops.dense.ingest_scatter` over in-range slots
     (host prep drops nothing — callers bound slots beforehand)."""
-    tile_ids, valid, lt_d, val_d, tomb_d = prepare_tile_updates(
-        np.asarray(slots, np.int64), np.asarray(lt, np.int64),
-        np.asarray(val, np.int64), np.asarray(tomb), store.lt.shape[0])
+    with span("scatter.prepare", kind="ingest", rows=len(slots)):
+        tile_ids, valid, lt_d, val_d, tomb_d = prepare_tile_updates(
+            np.asarray(slots, np.int64), np.asarray(lt, np.int64),
+            np.asarray(val, np.int64), np.asarray(tomb),
+            store.lt.shape[0])
     name = _obs_device.kernel_label("pallas.ingest_scatter_tiles",
                                     interpret)
     with _obs_device.record(name, dim=int(tile_ids.shape[0]),

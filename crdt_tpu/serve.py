@@ -39,8 +39,9 @@ the wire-compat tests in tests/test_serve.py prove both directions
 bit-compatible.
 
 Backpressure is explicit and measured (`MetricsRegistry`):
-``crdt_tpu_serve_sessions`` / ``_queue_depth`` gauges,
-``_flush_seconds`` / ``_ack_seconds`` histograms,
+``crdt_tpu_serve_sessions`` / ``_queue_depth`` gauges, the
+``_ack_seconds`` histogram and its ``_ack_phase_seconds`` /
+``_read_phase_seconds`` decompositions,
 ``_shed_total{lane=admission|cold}``, and an admission watermark —
 sessions past ``max_sessions`` are refused at accept with the same
 pre-hello ``busy`` frame `SyncServer` uses, so clients back off
@@ -72,6 +73,7 @@ from .net import (BINOP_DELETE, BINOP_GET, BINOP_PUT, BINOP_ST_BUSY,
                   WireTally, _flat_views, _pack_for_peer, _pack_split,
                   _recv_span, _unpack_split, decode_binop_request,
                   encode_binop_reply)
+from .obs.trace import span
 
 # First body byte of a binary op frame (docs/WIRE.md): a negotiated
 # session dispatches on it — JSON ops start with '{' (0x7b), so the
@@ -438,10 +440,6 @@ class ServeTier:
             "accept loops sharing this tier's port (SO_REUSEPORT "
             "multi-loop serving; 1 = single loop, incl. the "
             "no-SO_REUSEPORT fallback)")
-        self._m_flush = reg.histogram(
-            "crdt_tpu_serve_flush_seconds",
-            "combiner flush wall time under the serving tier, by "
-            "trigger")
         self._m_ack = reg.histogram(
             "crdt_tpu_serve_ack_seconds",
             "write enqueue-to-ack latency (queue wait + tick commit)")
@@ -466,12 +464,22 @@ class ServeTier:
         self._m_ack_phase = reg.histogram(
             "crdt_tpu_serve_ack_phase_seconds",
             "write-ack latency decomposed by phase: queue_wait (enqueue "
-            "to tick pickup), stamp (HLC send_batch), scatter (device "
-            "commit dispatch), ack_write (residual tick work + ack "
-            "fan-out)")
+            "to tick pickup), replica_wait (tick pickup to the commit "
+            "running on the replica executor), stamp (HLC send_batch), "
+            "scatter (device commit dispatch), ack_write (residual tick "
+            "work + ack fan-out)")
         self._m_ack_phase_sketch = reg.sketch(
             "crdt_tpu_serve_ack_phase_seconds_sketch",
             "write-ack phase latency, relative-error quantile sketch")
+        self._m_read_phase = reg.histogram(
+            "crdt_tpu_serve_read_phase_seconds",
+            "served read-frame latency decomposed by phase: decode "
+            "(binary lane: frame decode + admission), replica_wait "
+            "(submit to the read running on the replica executor), "
+            "store_wait (device work queued on the store ahead of the "
+            "read's slices), fetch (dispatching the read's slices, "
+            "their device work + device-to-host transfer), "
+            "reply (task end to reply drained)")
         self._m_moved = reg.counter(
             "crdt_tpu_serve_moved_total",
             "keyspace ops redirected with the moved reply (federated "
@@ -758,24 +766,13 @@ class ServeTier:
             self._ingest_cm = self.crdt.ingest(
                 auto_flush_rows=self._auto_flush_rows)
             self._wc = self._ingest_cm.__enter__()
-            self._wc.on_flush = self._note_flush
 
     def _close_ingest(self) -> None:
         with self.lock:
-            wc, self._wc = self._wc, None
-            if wc is not None:
-                wc.on_flush = None
+            self._wc = None
             cm, self._ingest_cm = self._ingest_cm, None
             if cm is not None:
                 cm.__exit__(None, None, None)
-
-    def _note_flush(self, trigger: str, rows: int,
-                    seconds: float) -> None:
-        # WriteCombiner flush listener — fires on EVERY trigger (tick,
-        # auto, any barrier a pack/merge drains through), so the
-        # histogram sees the tier's true flush distribution.
-        self._m_flush.observe(seconds, node=self._node,
-                              trigger=trigger)
 
     # --- write path: queue -> one combiner tick ---
 
@@ -829,7 +826,7 @@ class ServeTier:
             batches = [(e[1], e[2], e[3]) for e in bq]
             phases = await self._loop.run_in_executor(
                 self._replica_pool, self._commit, slots, vals, tombs,
-                batches)
+                batches, tick_t, writes)
             if self._lease_expired():
                 outcome: Any = ("busy", "primary lease expired "
                                         "(fenced; retry)")
@@ -859,18 +856,20 @@ class ServeTier:
             outcome = f"{type(e).__name__}: {e}"
         now = time.perf_counter()
         # Ack attribution (SERVE_r01 follow-up): every write in the
-        # tick shares the combiner's stamp/scatter legs; queue_wait is
-        # per write; ack_write is the residual tick time the phase
-        # timers don't cover (queue drain, executor hop, ack fan-out).
-        # Per-write observation keeps sum(phase sums) comparable to
-        # the crdt_tpu_serve_ack_seconds sum. Failed ticks committed
-        # nothing, so nothing is attributed. A binop batch is ONE
-        # client-visible ack (one reply frame), so it is one
-        # observation — with its decode+admission cost attributed to
-        # the binary-lane-only `decode` phase.
-        stamp = float(phases.get("stamp", 0.0)) if phases else 0.0
-        scatter = float(phases.get("scatter", 0.0)) if phases else 0.0
-        ack_write = max(0.0, (now - tick_t) - stamp - scatter)
+        # tick shares the commit's replica_wait/stamp/scatter legs;
+        # queue_wait is per write; ack_write is the residual tick time
+        # the phase timers don't cover (queue drain, executor hop
+        # back, ack fan-out). Per-write observation keeps sum(phase
+        # sums) comparable to the crdt_tpu_serve_ack_seconds sum.
+        # Failed ticks committed nothing, so nothing is attributed. A
+        # binop batch is ONE client-visible ack (one reply frame), so
+        # it is one observation — with its decode+admission cost
+        # attributed to the binary-lane-only `decode` phase.
+        replica_wait = phases.get("replica_wait", 0.0)
+        stamp = phases.get("stamp", 0.0)
+        scatter = phases.get("scatter", 0.0)
+        ack_write = max(0.0, (now - tick_t) - replica_wait - stamp
+                        - scatter)
         this_loop = self._loop
         for e in entries:
             fut, t0, floop = e[4], e[5], e[-1]
@@ -902,6 +901,8 @@ class ServeTier:
                 queue_wait = max(0.0, tick_t - t0 - dec)
                 self._m_ack_phase.observe(
                     queue_wait, phase="queue_wait", node=self._node)
+                self._m_ack_phase.observe(
+                    replica_wait, phase="replica_wait", node=self._node)
                 self._m_ack_phase.observe(stamp, phase="stamp",
                                           node=self._node)
                 self._m_ack_phase.observe(scatter, phase="scatter",
@@ -911,6 +912,8 @@ class ServeTier:
                 self._m_ack_phase_sketch.observe(
                     queue_wait, phase="queue_wait", node=self._node)
                 self._m_ack_phase_sketch.observe(
+                    replica_wait, phase="replica_wait", node=self._node)
+                self._m_ack_phase_sketch.observe(
                     stamp, phase="stamp", node=self._node)
                 self._m_ack_phase_sketch.observe(
                     scatter, phase="scatter", node=self._node)
@@ -919,21 +922,30 @@ class ServeTier:
         await self._fanout_all()
 
     def _commit(self, slots: np.ndarray, vals: np.ndarray,
-                tombs: np.ndarray, batches: list) -> dict:
+                tombs: np.ndarray, batches: list, tick_t: float,
+                writes: int) -> dict:
+        """The tick's ``writes`` committed on the replica executor.
+        Returns its ack phases: ``replica_wait`` (tick pickup until
+        this runs with the replica lock held) and the combiner's
+        ``stamp``/``scatter``."""
         with self.lock:
+            phases = {"replica_wait": time.perf_counter() - tick_t}
             wc = self._wc
-            if len(slots):
-                self.crdt.put_batch(slots, vals, tombs)
-            # Each binop frame stages as its own stamp group — its
-            # wire views land straight in the combiner's columnar
-            # staging — but the tick still ends in ONE send_batch and
-            # ONE ingest_scatter (the dispatch-ledger invariant).
-            for bs, bv, bt in batches:
-                self.crdt.put_batch(bs, bv, bt)
-            if wc is not None:
-                wc.flush("tick")
-                return dict(wc.last_phase_seconds)
-        return {}
+            with span("serve.commit", kind="serve", node=self._node,
+                      writes=writes):
+                if len(slots):
+                    self.crdt.put_batch(slots, vals, tombs)
+                # Each binop frame stages as its own stamp group — its
+                # wire views land straight in the combiner's columnar
+                # staging — but the tick still ends in ONE send_batch
+                # and ONE ingest_scatter (the dispatch-ledger
+                # invariant).
+                for bs, bv, bt in batches:
+                    self.crdt.put_batch(bs, bv, bt)
+                if wc is not None:
+                    wc.flush("tick")
+                    phases.update(wc.last_phase_seconds)
+        return phases
 
     # --- watch fan-out: one pack per flush tick, pushed to every
     # watcher of a touched slot (docs/FEDERATION.md) ---
@@ -1222,10 +1234,6 @@ class ServeTier:
             # packed merge path.
             caps.add("replication")
         return caps
-
-    def _read_slot(self, slot: int):
-        with self.lock:
-            return self.crdt.get(slot)
 
     def _merge_json(self, payload: str, tctx=None) -> None:
         with _recv_span("push", tctx):
@@ -1546,11 +1554,32 @@ class ServeTier:
         self._m_moved.inc(op=str(msg.get("op")), node=self._node)
         return verdict
 
-    def _read_slots(self, slots: np.ndarray) -> list:
-        # Batched point reads for a binop frame: one lock hold, one
-        # executor hop for every `get` in the frame.
+    def _read_slots(self, slots, t_submit: float) -> tuple:
+        """Point reads for one frame: one lock hold, one executor hop
+        for every `get` in it. Returns the values, the read phases up
+        to the values on the host, and when the task ended (the
+        start of the ``reply`` phase, `_observe_read`)."""
         with self.lock:
-            return [self.crdt.get(int(s)) for s in slots]
+            t_run = time.perf_counter()
+            crdt = self.crdt
+            waited = crdt.store_wait_seconds
+            with span("serve.read", kind="serve", node=self._node,
+                      reads=len(slots)):
+                values = [crdt.get(int(s)) for s in slots]
+            t_end = time.perf_counter()
+            store_wait = crdt.store_wait_seconds - waited
+        return values, {"replica_wait": t_run - t_submit,
+                        "store_wait": store_wait,
+                        "fetch": (t_end - t_run) - store_wait}, t_end
+
+    def _observe_read(self, phases: dict, t_end: float) -> None:
+        """One observation per phase for a read frame whose reply has
+        drained: together the phases cover the frame's time from
+        decode (or submit) to the drain."""
+        phases["reply"] = time.perf_counter() - t_end
+        for name, seconds in phases.items():
+            self._m_read_phase.observe(seconds, phase=name,
+                                       node=self._node)
 
     async def _binop_frame(self, ctx: _LoopCtx, body: bytes,
                            writer: asyncio.StreamWriter,
@@ -1647,10 +1676,16 @@ class ServeTier:
                     details.append({"code": "write_rejected",
                                     "error": str(outcome)})
         gmask = ok & (opcodes == BINOP_GET)
+        read_phases = None
         if gmask.any():
             gidx = np.nonzero(gmask)[0]
-            read = await ctx.loop.run_in_executor(
-                self._replica_pool, self._read_slots, slots[gidx])
+            # `decode` runs to the submit: for a frame that also
+            # writes, it includes waiting for those writes' ack.
+            t_submit = time.perf_counter()
+            read, read_phases, t_end = await ctx.loop.run_in_executor(
+                self._replica_pool, self._read_slots, slots[gidx],
+                t_submit)
+            read_phases["decode"] = t_submit - t0
             if values_out is None:
                 values_out = np.zeros(n, np.int64)
             for i, value in zip(gidx, read):
@@ -1665,6 +1700,8 @@ class ServeTier:
             await writer.drain()
         except (ConnectionError, OSError):
             return False
+        if read_phases is not None:
+            self._observe_read(read_phases, t_end)
         return True
 
     async def _session_loop(self, ctx: _LoopCtx,
@@ -1766,11 +1803,13 @@ class ServeTier:
                     await write_json_async(writer, routed, codec,
                                            self.tally)
                     continue
-                value = await loop.run_in_executor(
-                    self._replica_pool, self._read_slot, slot)
+                values, read_phases, t_end = await loop.run_in_executor(
+                    self._replica_pool, self._read_slots, (slot,),
+                    time.perf_counter())
                 await write_json_async(writer,
-                                       {"ok": True, "value": value},
+                                       {"ok": True, "value": values[0]},
                                        codec, self.tally)
+                self._observe_read(read_phases, t_end)
 
             elif op == "hello":
                 want = msg.get("caps")

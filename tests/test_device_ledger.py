@@ -235,3 +235,28 @@ def test_register_is_import_time_not_dispatch_time():
     assert {"mod.kernel_a",
             "mod.kernel_b"} <= set(led.registered_kernels())
     assert led.dispatches() == 0
+
+
+def test_dispatch_is_named_in_the_profile(tmp_path):
+    """A recorded dispatch opens a profiler annotation named after its
+    kernel: captured, it is an event of that name on the host plane,
+    on the same clock as the device's operations."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+    led = DispatchLedger(MetricsRegistry())
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with led.record("mod.profiled_kernel", dim=8):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert "mod.profiled_kernel" in names
+    assert led.dispatches("mod.profiled_kernel") == 1

@@ -158,6 +158,22 @@ def test_overlay_answers_point_reads_without_flushing():
     assert c.get(0) is None
 
 
+def test_only_store_reads_count_a_store_wait():
+    """A read the store answers waits out the device work queued on the
+    store and counts that wait in `store_wait_seconds`; an overlay hit
+    touches neither, and neither flushes."""
+    c = DenseCrdt("n", N, wall_clock=FakeClock(start=BASE))
+    c.put_batch([0], [5])
+    with c.ingest() as wc:
+        c.put_batch([1], [11])
+        before = c.store_wait_seconds
+        assert c.get(1) == 11
+        assert c.store_wait_seconds == before
+        assert c.get(0) == 5
+        assert c.store_wait_seconds > before
+        assert wc.flushes == 0
+
+
 def test_count_modified_since_includes_staged_rows():
     c = DenseCrdt("n", N, wall_clock=FakeClock(start=BASE))
     c.put_batch([0], [1])

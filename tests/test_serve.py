@@ -360,20 +360,28 @@ def test_metrics_op_reports_serve_instruments():
         with _connect(tier) as sock:
             assert _req(sock, {"op": "put", "slot": 1,
                                "value": 2}) == {"ok": True}
+            assert _req(sock, {"op": "get", "slot": 1}) == {
+                "ok": True, "value": 2}
             send_frame(sock, {"op": "bye"})
         snap = fetch_metrics(tier.host, tier.port)
     assert "crdt_tpu_serve_sessions" in snap["gauges"]
     assert "crdt_tpu_serve_ops_total" in snap["counters"]
     assert "crdt_tpu_serve_ack_seconds" in snap["histograms"]
-    assert "crdt_tpu_serve_flush_seconds" in snap["histograms"]
+    assert "crdt_tpu_serve_read_phase_seconds" in snap["histograms"]
 
 
-# --- ack attribution (PR 11): queue_wait / stamp / scatter / ack_write ---
+# --- ack attribution: queue_wait / replica_wait / stamp / scatter /
+# ack_write ---
+
+ACK_PHASES = ("queue_wait", "replica_wait", "stamp", "scatter",
+              "ack_write")
+
 
 def test_ack_phase_attribution_sums_to_ack():
-    """Every acked write decomposes into queue_wait + stamp + scatter
-    + ack_write; the phase-histogram sums must reconstruct the ack
-    histogram's sum (per-write observation, shared tick legs)."""
+    """Every acked write decomposes into queue_wait + replica_wait +
+    stamp + scatter + ack_write; the phase-histogram sums must
+    reconstruct the ack histogram's sum (per-write observation, shared
+    tick legs)."""
     crdt = DenseCrdt("phase-a", n_slots=64)
     node = str(crdt.node_id)
     reg = default_registry()
@@ -393,11 +401,10 @@ def test_ack_phase_attribution_sums_to_ack():
                                    "value": i})["ok"] is True
             send_frame(sock, {"op": "bye"})
     ack_sum = _sum(ack, node=node) - ack0
-    phases = {p: _sum(phase, node=node, phase=p)
-              for p in ("queue_wait", "stamp", "scatter", "ack_write")}
+    phases = {p: _sum(phase, node=node, phase=p) for p in ACK_PHASES}
     counts = {p: sum(s["count"] for s in phase.samples()
                      if s["labels"] == {"node": node, "phase": p})
-              for p in ("queue_wait", "stamp", "scatter", "ack_write")}
+              for p in ACK_PHASES}
     # one observation per phase per acked write
     assert counts["queue_wait"] == 20
     assert counts == {p: 20 for p in counts}
@@ -700,7 +707,7 @@ def test_binop_ack_phases_include_decode_and_reconstruct():
                    if all(s["labels"].get(k) == v
                           for k, v in labels.items()))
 
-    names = ("decode", "queue_wait", "stamp", "scatter", "ack_write")
+    names = ("decode",) + ACK_PHASES
     ack0 = _sum(ack, node=node)
     frames = 10
     with ServeTier(crdt, flush_interval=0.002) as tier:
@@ -725,6 +732,143 @@ def test_binop_ack_phases_include_decode_and_reconstruct():
     total = sum(_sum(phase, node=node, phase=p) for p in names)
     assert total == pytest.approx(ack_sum, rel=0.10), \
         (counts, total, ack_sum)
+
+
+# --- read attribution: decode / replica_wait / store_wait / fetch /
+# reply ---
+
+READ_PHASES = ("replica_wait", "store_wait", "fetch", "reply")
+
+
+def _spy_reads(tier) -> list:
+    """The phases of every read frame the tier observes, in order."""
+    seen = []
+    observe = tier._observe_read
+
+    def spy(phases, t_end):
+        observe(phases, t_end)
+        seen.append(dict(phases))
+
+    tier._observe_read = spy
+    return seen
+
+
+def _phase_totals(name, node):
+    """``phase -> (count, sum)`` of one phase histogram for a node."""
+    return {s["labels"]["phase"]: (s["count"], s["sum"])
+            for s in default_registry().histogram(name).samples()
+            if s["labels"].get("node") == node}
+
+
+def test_read_phases_observed_once_per_frame_within_round_trip():
+    """Every read frame through the replica executor, binary or JSON,
+    observes each of its phases exactly once; the phases are
+    non-negative and together take no longer than the client's round
+    trip."""
+    crdt = DenseCrdt("readphase-a", n_slots=64)
+    node = str(crdt.node_id)
+    rtts = []
+    with ServeTier(crdt, flush_interval=0.002) as tier:
+        seen = _spy_reads(tier)
+        bsock, codec = _binop_session(tier.host, tier.port)
+        with bsock, _connect(tier) as jsock:
+            assert _req(jsock, {"op": "put", "slot": 5,
+                                "value": 50}) == {"ok": True}
+            for i in range(3):
+                t = time.perf_counter()
+                status, values, _ = binop_round(
+                    bsock, [BINOP_GET, BINOP_GET], [5, 6 + i], [0, 0],
+                    deadline=time.monotonic() + 10.0, codec=codec)
+                rtts.append(time.perf_counter() - t)
+                assert list(status) == [BINOP_ST_OK, BINOP_ST_OK_NULL]
+                assert int(values[0]) == 50
+            for i in range(2):
+                t = time.perf_counter()
+                assert _req(jsock, {"op": "get", "slot": 5}) == {
+                    "ok": True, "value": 50}
+                rtts.append(time.perf_counter() - t)
+            send_frame(bsock, {"op": "bye"}, None, codec)
+            send_frame(jsock, {"op": "bye"})
+    counts = {p: c for p, (c, _) in _phase_totals(
+        "crdt_tpu_serve_read_phase_seconds", node).items()}
+    assert counts == dict({p: 5 for p in READ_PHASES}, decode=3)
+    assert [sorted(p) for p in seen] == \
+        [sorted(("decode",) + READ_PHASES)] * 3 \
+        + [sorted(READ_PHASES)] * 2
+    for phases, rtt in zip(seen, rtts):
+        assert all(v >= 0.0 for v in phases.values()), phases
+        assert sum(phases.values()) <= rtt, (phases, rtt)
+
+
+def test_replica_wait_names_a_busy_replica_executor():
+    """A read and a commit queued behind a 0.3 s task on the replica
+    executor show that wait as their `replica_wait` phase, and only
+    there."""
+    crdt = DenseCrdt("replwait-a", n_slots=64)
+    node = str(crdt.node_id)
+    ack_name = "crdt_tpu_serve_ack_phase_seconds"
+    with ServeTier(crdt, flush_interval=0.002) as tier:
+        seen = _spy_reads(tier)
+        with _connect(tier) as sock:
+            # The first commit and the first read compile their
+            # programs: warm both before timing anything.
+            assert _req(sock, {"op": "put", "slot": 1,
+                               "value": 1}) == {"ok": True}
+            assert _req(sock, {"op": "get", "slot": 2}) == {
+                "ok": True, "value": None}
+            tier._replica_pool.submit(time.sleep, 0.3)
+            assert _req(sock, {"op": "get", "slot": 1}) == {
+                "ok": True, "value": 1}
+            ack0 = _phase_totals(ack_name, node)
+            tier._replica_pool.submit(time.sleep, 0.3)
+            assert _req(sock, {"op": "put", "slot": 3,
+                               "value": 3}) == {"ok": True}
+            send_frame(sock, {"op": "bye"})
+    read = seen[-1]
+    assert read["replica_wait"] >= 0.2, read
+    assert all(v < 0.1 for p, v in read.items()
+               if p != "replica_wait"), read
+    ack1 = _phase_totals(ack_name, node)
+    ack = {p: ack1[p][1] - ack0[p][1] for p in ACK_PHASES}
+    assert {p: ack1[p][0] - ack0[p][0] for p in ACK_PHASES} == \
+        {p: 1 for p in ACK_PHASES}
+    assert ack["replica_wait"] >= 0.2, ack
+    assert all(v < 0.1 for p, v in ack.items()
+               if p != "replica_wait"), ack
+
+
+def test_serve_spans_carry_read_and_write_counts():
+    """With the trace ring on, the commit and the read on the replica
+    executor emit `serve.commit` with the tick's write count and
+    `serve.read` with the frame's read count."""
+    from crdt_tpu.obs.trace import tracer
+    ring = tracer()
+    was_enabled = ring.enabled
+    ring.enable()
+    try:
+        crdt = DenseCrdt("spans-a", n_slots=64)
+        with ServeTier(crdt, flush_interval=0.002) as tier:
+            sock, codec = _binop_session(tier.host, tier.port)
+            with sock:
+                status, _, _ = binop_round(
+                    sock, [BINOP_PUT, BINOP_PUT], [1, 2], [10, 20],
+                    deadline=time.monotonic() + 10.0, codec=codec)
+                assert list(status) == [BINOP_ST_OK] * 2
+                status, _, _ = binop_round(
+                    sock, [BINOP_GET] * 3, [1, 2, 3], [0, 0, 0],
+                    deadline=time.monotonic() + 10.0, codec=codec)
+                assert list(status) == [BINOP_ST_OK] * 2 \
+                    + [BINOP_ST_OK_NULL]
+                send_frame(sock, {"op": "bye"}, None, codec)
+        events = [e for e in ring.events("serve")
+                  if e.get("node") == "spans-a"]
+    finally:
+        if not was_enabled:
+            ring.disable()
+    assert [(e["span"], e.get("writes"), e.get("reads"))
+            for e in events] == [("serve.commit", 2, None),
+                                 ("serve.read", None, 3)]
+    assert all(e["dur_s"] >= 0.0 for e in events)
 
 
 # --- SO_REUSEPORT multi-loop serving ---
