@@ -266,11 +266,11 @@ def one_chip(rehearse: bool, seed: int, meter: CompileMeter) -> None:
     start_ms = int(time.time() * 1000)
 
     served = DenseCrdt("a", n, executor=executor)
-    placed = {d for lane in served.store for d in lane.devices()}
+    placed = {d for lane in served._held for d in lane.devices()}
     check(placed == {dev}, f"store lanes on {placed}, not {dev}")
-    check(served._use_pallas() and served._use_pallas_scatter(),
+    check(served._use_pallas() and served._planes is not None,
           "the merge or ingest gate did not take the Mosaic kernel")
-    resident = sum(int(lane.nbytes) for lane in served.store)
+    resident = sum(int(lane.nbytes) for lane in served._held)
     emit("setup", n_slots=n, store_bytes=resident, executor=executor,
          seconds=time.perf_counter() - t0, **meter.snapshot())
 
@@ -331,6 +331,7 @@ def one_chip(rehearse: bool, seed: int, meter: CompileMeter) -> None:
     t = time.perf_counter()
     tier = ServeTier(served).start()
     stopped = False
+    views0 = default_ledger().dispatches("dense.store_view")
     try:
         with ThreadPoolExecutor(len(blocks)) as pool:
             logs = list(pool.map(
@@ -344,8 +345,13 @@ def one_chip(rehearse: bool, seed: int, meter: CompileMeter) -> None:
         touched = set(int(k) for k in keys)
         check_reads(read(sorted(touched)),
                     {k: oracle.get(k) for k in touched}, "serve readback")
+        # Served writes and reads run on the resident planes: no
+        # joined view of the store is built for them.
+        views = default_ledger().dispatches("dense.store_view") - views0
+        check(views == 0, f"the served phase built {views} store views")
         emit("serve", sessions=len(blocks), ops_acked=acked,
-             keys=len(touched), seconds=time.perf_counter() - t,
+             keys=len(touched), store_views=views,
+             seconds=time.perf_counter() - t,
              **spans.take(), **meter.snapshot())
 
         # --- anti-entropy with a second 16M-slot peer ---
@@ -479,8 +485,8 @@ def one_chip(rehearse: bool, seed: int, meter: CompileMeter) -> None:
     names = default_ledger().as_dict()
     mosaic = sorted(k for k in names if k.startswith("pallas."))
     interpreted = [k for k in names if k.endswith("[interpret]")]
-    check("pallas.ingest_scatter_tiles" + ("[interpret]" if rehearse
-                                           else "") in names,
+    check("pallas.ingest_scatter_planes" + ("[interpret]" if rehearse
+                                            else "") in names,
           "no ingest commit went through the Mosaic kernel")
     check(bool(interpreted) == rehearse,
           f"interpret-mode dispatches on the chip: {interpreted}")

@@ -77,7 +77,7 @@ def _registry_completeness() -> List:
 # order-sensitivity hazard into the hottest dispatch (docs/FASTPATH.md).
 _FASTPATH_REQUIRED = (
     "dense.merge_repack_step",
-    "pallas.ingest_scatter_tiles[interpret]",
+    "pallas.ingest_scatter_planes[interpret]",
     "parallel.collective_join[member2]",
 )
 
@@ -153,8 +153,12 @@ _LEDGER_REQUIRED = (
     "dense.ingest_scatter",
     # ops/digest.py — the merkle reduction
     "digest.digest_tree_device",
-    # ops/pallas_scatter.py + ops/pallas_merge.py — Mosaic routes
-    "pallas.ingest_scatter_tiles",
+    # ops/pallas_scatter.py — the resident planes: the in-place
+    # flush, the one-gather point read, and the cold paths' view join
+    # and split (either of those two on a served path is a miss)
+    "pallas.ingest_scatter_planes", "dense.point_read",
+    "dense.store_view", "dense.store_split",
+    # ops/pallas_merge.py — Mosaic merge routes
     "pallas.model_fanin_batch", "pallas.model_fanin_split",
     "pallas.pipelined_model_step", "pallas.pipelined_model_step_split",
     # semantics/kernels.py — the typed fan-in family

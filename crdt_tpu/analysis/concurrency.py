@@ -106,7 +106,8 @@ _DEVICE_DISPATCH = {
     "wire_join_step", "merge_repack_step", "delta_mask",
     "range_delta_mask", "max_logical_time", "put_scatter",
     "record_scatter", "delete_scatter", "ingest_scatter",
-    "digest_tree_device", "ingest_scatter_tiles", "model_fanin_batch",
+    "digest_tree_device", "ingest_scatter_planes", "gather_slot",
+    "store_to_planes", "planes_to_store", "model_fanin_batch",
     "model_fanin_split", "pipelined_model_step",
     "pipelined_model_step_split", "typed_wire_join_step",
     "typed_sparse_join_step", "typed_fanin_step", "sharded_fanin",

@@ -27,7 +27,7 @@ reproduced bugs):
   aliased and its contents are undefined after the call.
 - ``scatter-combiner-bypass`` — calling a store scatter wrapper
   (``put_scatter``/``record_scatter``/``delete_scatter``/
-  ``ingest_scatter``/``ingest_scatter_tiles``) in a function with no
+  ``ingest_scatter``/``ingest_scatter_planes``) in a function with no
   visible ingest gate (no ``drain_ingest`` call and no ``_ingest``
   check before the call); a staged ``ingest()`` window would commit
   its backlog AFTER such a write, stamping over it out of HLC order.
@@ -165,7 +165,7 @@ _WALL_CALLS = {
 }
 _HLC_ATTRS = {"hlc", "canonical_time", "_canonical_time", "logical_time"}
 _DONATING_WRAPPERS = {"put_scatter", "record_scatter", "delete_scatter",
-                      "ingest_scatter", "ingest_scatter_tiles"}
+                      "ingest_scatter", "ingest_scatter_planes"}
 _COMBINER_SCATTERS = _DONATING_WRAPPERS
 # Lexical evidence that a function respects the write-combiner barrier:
 # it drains the window, or it branches on the staging handle.

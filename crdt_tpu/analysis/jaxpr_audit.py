@@ -387,12 +387,13 @@ def builtin_targets(include_sharded: bool = True) -> List[AuditTarget]:
         build=_build_pallas_step))
 
     targets.append(AuditTarget(
-        name="pallas.ingest_scatter_tiles[interpret]",
+        name="pallas.ingest_scatter_planes[interpret]",
         unique_slots=True,
-        notes="touched-tile ingest commit kernel (ops/pallas_scatter); "
-              "combiner dedups slots before host prep; interpret "
-              "mode, trace-only",
-        build=_build_pallas_ingest_scatter))
+        notes="touched-tile ingest commit kernel on the resident 2-D "
+              "planes (ops/pallas_scatter _planes_step), aliased in "
+              "place; combiner dedups slots before host prep; "
+              "interpret mode, trace-only",
+        build=_build_pallas_planes_scatter))
 
     # The per-shard body of parallel/fanin.py's _pallas_fanin_block
     # (split -> pallas_fanin_batch -> join) audited at the per-device
@@ -455,22 +456,20 @@ def _build_pallas_step():
                                 np.int64(0))
 
 
-def _build_pallas_ingest_scatter():
+def _build_pallas_planes_scatter():
     import jax
     import numpy as np
     from ..ops import pallas_scatter as ps
-    from ..ops.dense import empty_dense_store
 
-    n = ps.TILE  # one touched tile
-    store = empty_dense_store(n)
+    planes = ps.empty_planes(ps.TILE)  # one touched tile
     tile_ids = np.zeros((1,), np.int32)
     valid = np.zeros((ps._SB, ps._LANE), np.int32)
     lt_d = np.zeros((ps._SB, ps._LANE), np.int64)
     val_d = np.zeros((ps._SB, ps._LANE), np.int64)
     tomb_d = np.zeros((ps._SB, ps._LANE), np.int32)
     me = np.zeros((1,), np.int32)
-    return jax.make_jaxpr(ps._scatter_jit(False, True))(
-        store, tile_ids, valid, lt_d, val_d, tomb_d, me)
+    return jax.make_jaxpr(ps._planes_jit(False, True))(
+        planes, tile_ids, valid, lt_d, val_d, tomb_d, me)
 
 
 def _build_pallas_block_per_shard():

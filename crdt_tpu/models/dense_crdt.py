@@ -47,6 +47,7 @@ from ..ops.dense import (DenseChangeset, DenseStore, FaninResult, _NEG,
                          merge_repack_step, pad_replica_rows, put_scatter,
                          sparse_fanin_step, store_to_changeset)
 from ..ops.merge import recv_guards
+from ..ops.pallas_merge import SplitStore
 from ..ops.packing import NodeTable, PackedDelta, pack_into_arena
 from ..record import (KeyDecoder, KeyEncoder, Record, ValueDecoder,
                       ValueEncoder)
@@ -159,7 +160,16 @@ class DenseCrdt:
         self._table = NodeTable(node_ids or [])
         # A caller-supplied store counts as escaped: the caller may
         # still hold it, so write scatters must not donate its buffers.
+        # Planes are never handed out, so it is False whenever the
+        # store is held as planes: the split into planes clears it.
         self._store_escaped = store is not None
+        # The store is held in ONE form at a time: `_planes` (the
+        # resident `SplitStore` planes the served flush and point read
+        # use, where `_resident_for` holds) or `_store_lanes`
+        # (`DenseStore` lanes, which cold paths read); the other is
+        # None. See the `_store` getter and `_served_planes`.
+        self._planes: Optional[SplitStore] = None
+        self._store_lanes: Optional[DenseStore] = None
         # pack_since cache (watermark key -> packed delta); must exist
         # before the first store assignment — the _store setter clears
         # it on every replacement.
@@ -186,12 +196,15 @@ class DenseCrdt:
         # peer's initial full sync). Retired by compact (the remap
         # invalidates slot identity).
         self._gc_fence_dev = None
-        self._store = store if store is not None else empty_dense_store(
-            n_slots)
-        if self._store.n_slots != n_slots:  # must survive `python -O`
+        if store is not None and store.n_slots != n_slots:
+            # must survive `python -O`
             raise ValueError(
-                f"store holds {self._store.n_slots} slots but "
+                f"store holds {store.n_slots} slots but "
                 f"n_slots={n_slots}")
+        self._store = store if store is not None else self._empty_store(
+            n_slots)
+        # A given store moves to the planes now, and is not kept.
+        self._served_planes()
         if node_id not in self._table:
             self._intern_ids([node_id])
         self.stats = MergeStats().register(backend="DenseCrdt",
@@ -223,7 +236,9 @@ class DenseCrdt:
 
     @property
     def n_slots(self) -> int:
-        return self._store.n_slots
+        if self._planes is not None:
+            return self._planes.hi.size
+        return self._store_lanes.n_slots
 
     @property
     def canonical_time(self) -> Hlc:
@@ -231,15 +246,31 @@ class DenseCrdt:
 
     @property
     def _store(self) -> DenseStore:
+        """The store as `DenseStore` lanes, the form every cold path
+        (pack, digest, GC, compaction, checkpoint, grow, the XLA and
+        sparse merges) reads; the Mosaic merges take either form.
+        A store held as resident planes moves to lanes first: one
+        O(store) join, after which the planes are dropped, so the
+        store is never held twice. The next flush or point read moves
+        it back (`_served_planes`)."""
+        if self._planes is not None:
+            from ..ops.pallas_scatter import planes_to_store
+            self._store_lanes = planes_to_store(self._planes)
+            self._planes = None
         return self._store_lanes
 
     @_store.setter
-    def _store(self, store: DenseStore) -> None:
+    def _store(self, store) -> None:
         # The ONE choke point every mutation path shares (puts,
         # deletes, merges, grow, intern remaps): any store replacement
         # invalidates cached outbound packs, so `pack_since` can trust
-        # a cache hit without re-deriving what changed.
-        self._store_lanes = store
+        # a cache hit without re-deriving what changed. The store is
+        # held in the form it comes in: a commit's `SplitStore` as
+        # planes, a `DenseStore` as lanes.
+        if isinstance(store, SplitStore):
+            self._planes, self._store_lanes = store, None
+        else:
+            self._planes, self._store_lanes = None, store
         self._store_gen = self.__dict__.get("_store_gen", 0) + 1
         cache = self.__dict__.get("_pack_cache")
         if cache:
@@ -252,17 +283,40 @@ class DenseCrdt:
         """Live store lanes. Reading this marks the snapshot as
         escaped, which disables buffer donation on subsequent
         `put_batch`/`delete_batch` calls until the store is next
-        replaced — a snapshot you hold stays readable."""
+        replaced — a snapshot you hold stays readable. Resident planes
+        are never handed out: the next flush or point read splits the
+        store into planes of their own, so a held snapshot never stops
+        a commit donating the planes."""
         self.drain_ingest()
         self._store_escaped = True
         return self._store
+
+    @property
+    def _held(self):
+        """The store as it is held, planes or lanes (no device work)."""
+        return self._planes if self._planes is not None \
+            else self._store_lanes
+
+    def _served_planes(self) -> Optional[SplitStore]:
+        """The store as resident planes, for a served flush or point
+        read, or None where the store is not resident
+        (`_resident_for`). A store a cold path left as lanes moves
+        back first: one O(store) split, after which the lanes are
+        dropped (a caller still holding them as `store` keeps its
+        snapshot)."""
+        if self._planes is None and self._resident_for(self.n_slots):
+            from ..ops.pallas_scatter import store_to_planes
+            self._planes = store_to_planes(self._store_lanes)
+            self._store_lanes = None
+            self._store_escaped = False
+        return self._planes
 
     def wait_store(self) -> None:
         """Block until device work already queued on the store's lanes
         (earlier commits and merges) has finished. Unlike `store`, it
         neither drains the ingest window nor marks the store escaped,
         so later commits still donate the lanes."""
-        jax.block_until_ready(self._store)
+        jax.block_until_ready(self._held)
 
     @property
     def store_generation(self) -> int:
@@ -281,8 +335,13 @@ class DenseCrdt:
 
     def refresh_canonical_time(self) -> None:
         self.drain_ingest()
-        self._canonical_time = Hlc.from_logical_time(
-            int(dense_max_logical_time(self._store)), self._node_id)
+        if self._planes is not None:
+            from ..ops.pallas_scatter import planes_max_logical_time
+            lt = planes_max_logical_time(self._planes)
+        else:
+            lt = dense_max_logical_time(self._store_lanes)
+        self._canonical_time = Hlc.from_logical_time(int(lt),
+                                                     self._node_id)
 
     def _canonical_lt(self) -> jax.Array:
         """The canonical logicalTime as a device scalar — the live
@@ -520,7 +579,7 @@ class DenseCrdt:
         if self._store_escaped:
             return False
         try:
-            return next(iter(self._store.lt.devices())).platform != "cpu"
+            return next(iter(self._held[0].devices())).platform != "cpu"
         except Exception:
             return False
 
@@ -621,45 +680,48 @@ class DenseCrdt:
             staged, v = self._ingest.pending_value(slot)
             if staged:
                 return v
-        st = self._store
-        scalars = (st.occupied[slot], st.tomb[slot], st.val[slot])
-        # The slices queue on the device behind its earlier work on
+        occ, _, _, val, _, _, tomb = self._slot_fields(slot)
+        return val if occ and not tomb else None
+
+    def _slot_fields(self, slot: int) -> Tuple[bool, int, int, int, int,
+                                                int, bool]:
+        """``(occupied, lt, node, val, mod_lt, mod_node, tomb)`` of one
+        stored slot (no ingest overlay): one gathering dispatch, then
+        one device→host transfer. Every point read goes through here,
+        on resident planes where the store has them."""
+        from ..ops.pallas_scatter import decode_slot, gather_slot
+        self._served_planes()
+        words = gather_slot(self._held, slot)
+        # The read queues on the device behind its earlier work on
         # the store (commits, merges); that wait is counted apart from
         # the read's own fetch, after the dispatch so the device runs
-        # the slices straight after it.
+        # the read straight after it.
         t = time.perf_counter()
         self.wait_store()
         self.store_wait_seconds += time.perf_counter() - t
-        # One batched fetch: three sequential scalar reads pay three
-        # device→host round trips.
-        occ, tomb, val = jax.device_get(scalars)
-        return int(val) if bool(occ) and not bool(tomb) else None
+        return decode_slot(jax.device_get(words))
 
     def get_slot_record(self, slot: int) -> Optional[Record]:
         """Single-slot `Record` fetch (getRecord semantics,
-        crdt.dart:146) — ONE batched device→host transfer of seven
-        scalars, never a full-store readback (`record_map` is the
-        bulk shape; a 1M-slot replica must answer a point read in
+        crdt.dart:146) — ONE batched device→host transfer of the
+        slot's fields, never a full-store readback (`record_map` is
+        the bulk shape; a 1M-slot replica must answer a point read in
         O(1))."""
         self._check_slot(slot)
         # Records carry stamps, which staged rows only get at flush —
         # drain rather than synthesize an overlay answer.
         self.drain_ingest()
-        occ, lt, node, val, mod_lt, mod_node, tomb = jax.device_get(
-            (self._store.occupied[slot], self._store.lt[slot],
-             self._store.node[slot], self._store.val[slot],
-             self._store.mod_lt[slot], self._store.mod_node[slot],
-             self._store.tomb[slot]))
-        if not bool(occ):
+        occ, lt, node, val, mod_lt, mod_node, tomb = self._slot_fields(
+            slot)
+        if not occ:
             return None
         from ..hlc import MAX_COUNTER, SHIFT
         ids = self._table.ids()
-        lt, mod_lt = int(lt), int(mod_lt)
         return Record(
-            Hlc._raw(lt >> SHIFT, lt & MAX_COUNTER, ids[int(node)]),
-            None if bool(tomb) else int(val),
+            Hlc._raw(lt >> SHIFT, lt & MAX_COUNTER, ids[node]),
+            None if tomb else val,
             Hlc._raw(mod_lt >> SHIFT, mod_lt & MAX_COUNTER,
-                     ids[int(mod_node)]))
+                     ids[mod_node]))
 
     def contains_slot(self, slot: int) -> bool:
         """True if the slot holds a record, live OR tombstoned
@@ -668,7 +730,7 @@ class DenseCrdt:
         if self._ingest is not None \
                 and self._ingest.pending_value(slot)[0]:
             return True
-        return bool(self._store.occupied[slot])
+        return self._slot_fields(slot)[0]
 
     def is_deleted(self, slot: int) -> Optional[bool]:
         """None for never-written slots, else the tombstone flag
@@ -678,9 +740,8 @@ class DenseCrdt:
             staged, v = self._ingest.pending_value(slot)
             if staged:
                 return v is None
-        if not bool(self._store.occupied[slot]):
-            return None
-        return bool(self._store.tomb[slot])
+        occ, *_, tomb = self._slot_fields(slot)
+        return tomb if occ else None
 
     def clear(self, purge: bool = False) -> None:
         """Tombstone every LIVE slot with one batch HLC, or physically
@@ -695,7 +756,7 @@ class DenseCrdt:
         """Physically drop all records (crdt.dart:168-169). The
         canonical clock and node table are untouched."""
         self.drain_ingest()
-        self._store = empty_dense_store(self.n_slots)
+        self._store = self._empty_store(self.n_slots)
 
     # --- tombstone epoch GC + online compaction (docs/STORAGE.md) ---
 
@@ -989,9 +1050,8 @@ class DenseCrdt:
             staged, v = self._ingest.pending_value(slot)
             if staged:
                 return 0 if v is None else int(v)
-        occ, val = jax.device_get(
-            (self._store.occupied[slot], self._store.val[slot]))
-        return int(val) if bool(occ) else 0
+        occ, _, _, val, *_ = self._slot_fields(slot)
+        return val if occ else 0
 
     def _typed_spec(self, slot: int, *names):
         self._check_slot(slot)
@@ -1178,12 +1238,14 @@ class DenseCrdt:
                         and bool(np.any(slot_arr == k)), None))
             if unique else None)
 
-    def _emit_merge_wins(self, store: DenseStore, win) -> None:
-        """Winner change events from the fan-in's win mask — batched,
+    def _emit_merge_wins(self, win) -> None:
+        """Winner change events from the fan-in's win mask, read
+        against the store the merge just assigned — batched,
         post-dispatch (the device work is already queued); a subscriber
         costs one win-mask readback, never a per-record device loop."""
         if not self._hub.active:
             return
+        store = self._store
         win, tomb, val = jax.device_get((win, store.tomb, store.val))
         widx = np.nonzero(win)[0]
 
@@ -2089,7 +2151,7 @@ class DenseCrdt:
         if chunk < r:
             cs = pad_replica_rows(cs, chunk)
         new_store, pres, seen, voverflow = model_fanin_batch(
-            self._store, cs, canonical, local, jnp.int64(wall),
+            self._held, cs, canonical, local, jnp.int64(wall),
             chunk_rows=chunk,
             interpret=self._executor == "pallas-interpret",
             value_width=self._value_width)
@@ -2149,35 +2211,44 @@ class DenseCrdt:
         (the sharded model re-applies its NamedSharding here)."""
         return store
 
-    def _use_pallas_scatter(self) -> bool:
-        """Route the ingest commit through the touched-tile Mosaic
-        kernel? Stamp-blind overwrites don't care about the store's
-        semantics tags or table width, so the gates are only tile
-        alignment and a backend Mosaic can lower on (interpret mode
-        stands in off-TPU when forced)."""
+    def _resident_for(self, n_slots: int) -> bool:
+        """Hold a store of ``n_slots`` as resident split planes (and
+        commit it through the touched-tile Mosaic kernel)?
+        Stamp-blind overwrites don't care about the store's semantics
+        tags or table width, so the gates are only tile alignment and
+        a backend Mosaic can lower on (interpret mode stands in
+        off-TPU when forced)."""
         from ..ops.pallas_merge import TILE, mosaic_auto
-        if self.n_slots % TILE:
-            return False
-        if self._executor == "xla":
+        if n_slots % TILE or self._executor == "xla":
             return False
         if self._executor in ("pallas", "pallas-interpret"):
             return True
-        return mosaic_auto(self.n_slots, jax.devices()[0].platform)
+        return mosaic_auto(n_slots, jax.devices()[0].platform)
+
+    def _empty_store(self, n_slots: int):
+        """The never-written store of ``n_slots``, in the form it will
+        be held: planes built directly where they are resident."""
+        if self._resident_for(n_slots):
+            from ..ops.pallas_scatter import empty_planes
+            return empty_planes(n_slots)
+        return empty_dense_store(n_slots)
 
     def _commit_scatter(self, slots: np.ndarray, lt: np.ndarray,
-                        vals: np.ndarray, tombs: np.ndarray
-                        ) -> DenseStore:
+                        vals: np.ndarray, tombs: np.ndarray):
         """ONE device dispatch committing a deduped ingest batch
-        (`WriteCombiner.flush`'s scatter tail). Picks the touched-tile
-        Mosaic kernel when it engages, else the lax scatter with
-        power-of-two padded lanes; the sharded model overrides this
-        with one `shard_map` program (docs/FASTPATH.md)."""
+        (`WriteCombiner.flush`'s scatter tail). On resident planes the
+        touched-tile Mosaic kernel writes its tiles in place (planes
+        donated on every commit) and returns the planes; else the lax
+        scatter with power-of-two padded lanes; the sharded model
+        overrides this with one `shard_map` program
+        (docs/FASTPATH.md)."""
         me = self._table.ordinal(self.node_id)
-        if self._use_pallas_scatter():
-            from ..ops.pallas_scatter import ingest_scatter_tiles
+        planes = self._served_planes()
+        if planes is not None:
+            from ..ops.pallas_scatter import ingest_scatter_planes
             # crdtlint: disable=scatter-combiner-bypass -- only reached from the combiner's own flush, which IS the barrier
-            return ingest_scatter_tiles(
-                self._store, slots, lt, vals, tombs, me,
+            return ingest_scatter_planes(
+                planes, slots, lt, vals, tombs, me,
                 donate=self._donate_writes(),
                 interpret=self._executor == "pallas-interpret")
         # Fresh padded commit lanes every flush (power-of-two + slot ==
@@ -2305,7 +2376,7 @@ class DenseCrdt:
                 (new_store, new_canon, any_bad, overflow, drift,
                  val_ovf, first_idx, win_count, win, seen) = \
                     pipelined_model_step(
-                        self._store, cs, pipe.canonical, pipe.any_bad,
+                        self._held, cs, pipe.canonical, pipe.any_bad,
                         pipe.overflow, pipe.drift, pipe.val_overflow,
                         pipe.first_flag_idx,
                         jnp.int32(self._table.ordinal(self._node_id)),
@@ -2324,7 +2395,7 @@ class DenseCrdt:
             self._store = self._postprocess_store(new_store)
             self.stats.add_seen_lazy(seen)
             self.stats.add_adopted_lazy(win_count)
-            self._emit_merge_wins(new_store, win)
+            self._emit_merge_wins(win)
             return
         if not self._use_pallas():
             # The Mosaic route folds BOTH of these into its single
@@ -2407,7 +2478,7 @@ class DenseCrdt:
             pipe.merges += 1
             self._store = new_store
             self.stats.add_adopted_lazy(res.win_count)
-            self._emit_merge_wins(new_store, res.win)
+            self._emit_merge_wins(res.win)
             pipe.canonical = res.new_canonical
             self._pipe_send_bump(self._wall_clock())
             return
@@ -2455,7 +2526,7 @@ class DenseCrdt:
                 _sanitizer.check_dense_no_resurrection(
                     self._store, *self._gc_purged)
         self.stats.records_adopted += int(win_count)
-        self._emit_merge_wins(new_store, res.win)
+        self._emit_merge_wins(res.win)
         self._canonical_time = Hlc.send(
             Hlc.from_logical_time(int(new_canonical), self._node_id),
             millis=self._wall_clock())
@@ -2540,7 +2611,7 @@ class DenseCrdt:
                 (new_store, new_canon, any_bad, overflow, drift,
                  val_ovf, first_idx, win_count, win, seen) = \
                     pipelined_model_step_split(
-                        self._store, scs, jnp.asarray(node_map),
+                        self._held, scs, jnp.asarray(node_map),
                         pipe.canonical, pipe.any_bad, pipe.overflow,
                         pipe.drift, pipe.val_overflow,
                         pipe.first_flag_idx,
@@ -2559,13 +2630,13 @@ class DenseCrdt:
             self._store = self._postprocess_store(new_store)
             self.stats.add_seen_lazy(seen)
             self.stats.add_adopted_lazy(win_count)
-            self._emit_merge_wins(new_store, win)
+            self._emit_merge_wins(win)
             return
         wall = self._wall_clock()
         with merge_annotation("crdt_tpu.dense_merge",
                               hlc=lambda: self._canonical_time):
             new_store, pres, seen, voverflow = model_fanin_split(
-                self._store, scs, jnp.asarray(node_map),
+                self._held, scs, jnp.asarray(node_map),
                 self._canonical_lt(),
                 jnp.int32(self._table.ordinal(self._node_id)),
                 jnp.int64(wall), chunk_rows=chunk,
@@ -3108,6 +3179,10 @@ class ShardedDenseCrdt(DenseCrdt):
     def _write_sharding(self):
         from ..parallel import store_sharding
         return store_sharding(self._mesh)
+
+    def _resident_for(self, n_slots: int) -> bool:
+        # Key-sharded lanes commit through their own shard_map program.
+        return False
 
     def _commit_scatter(self, slots, lt, vals, tombs):
         # ONE shard_map program: every device takes its shard-local

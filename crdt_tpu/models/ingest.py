@@ -261,7 +261,7 @@ class WriteCombiner:
         # Store-bytes census: lane nbytes is array metadata, no device
         # work — the commit is where the store's footprint last moved.
         from ..obs import device as _obs_device
-        _obs_device.census(owner._store)
+        _obs_device.census(owner._held)
         flushes_c, rows_c, groups_c, seconds_h = _metrics()
         flushes_c.inc(trigger=trigger, node=node)
         rows_c.inc(d, node=node)

@@ -767,7 +767,8 @@ def _model_fanin_batch_jit(store, cs, canonical_lt, local_node,
     `DenseChangeset` in, wide store out — split/convert, the batch
     kernel, and the re-join all inside a single jit. Every separate
     dispatch costs a host round trip, and an unfused model merge was
-    paying four of them per call.
+    paying four of them per call. A store held as resident planes
+    goes in and comes out as planes (`_batch_on_store`).
 
     Returns ``(new_store, PallasFaninResult, seen, val_overflow)``
     where ``seen`` counts valid lanes (the stats counter) and
@@ -782,11 +783,26 @@ def _model_fanin_batch_jit(store, cs, canonical_lt, local_node,
         val_overflow = jnp.asarray(False)
         scs = split_changeset.__wrapped__(cs)
     seen = jnp.sum(cs.valid)
-    sst = split_store.__wrapped__(store)
+    out, res = _batch_on_store(store, scs, canonical_lt, local_node,
+                               wall_millis, chunk_rows, interpret)
+    return out, res, seen, val_overflow
+
+
+def _batch_on_store(store, scs, canonical_lt, local_node, wall_millis,
+                    chunk_rows, interpret):
+    """`pallas_fanin_batch` on the model's store in the form it holds:
+    a wide `DenseStore` is split in and joined out; resident 2-D
+    `SplitStore` planes (`ops.pallas_scatter`) go in and come back as
+    planes, with no whole-store split or join."""
+    if isinstance(store, SplitStore):
+        out, res = pallas_fanin_batch.__wrapped__(
+            store, scs, canonical_lt, local_node, wall_millis,
+            chunk_rows=chunk_rows, interpret=interpret)
+        return SplitStore(*(o.reshape(store.hi.shape) for o in out)), res
     out, res = pallas_fanin_batch.__wrapped__(
-        sst, scs, canonical_lt, local_node, wall_millis,
-        chunk_rows=chunk_rows, interpret=interpret)
-    return join_store.__wrapped__(out), res, seen, val_overflow
+        split_store.__wrapped__(store), scs, canonical_lt, local_node,
+        wall_millis, chunk_rows=chunk_rows, interpret=interpret)
+    return join_store.__wrapped__(out), res
 
 
 def model_fanin_batch(store, cs, canonical_lt, local_node, wall_millis,
@@ -847,11 +863,9 @@ def _model_fanin_split_jit(store, cs, node_map, canonical_lt,
     else:
         val_overflow = jnp.asarray(False)
     seen = jnp.sum(cs.hi != NEG_HI)
-    sst = split_store.__wrapped__(store)
-    out, res = pallas_fanin_batch.__wrapped__(
-        sst, cs, canonical_lt, local_node, wall_millis,
-        chunk_rows=chunk_rows, interpret=interpret)
-    return join_store.__wrapped__(out), res, seen, val_overflow
+    out, res = _batch_on_store(store, cs, canonical_lt, local_node,
+                               wall_millis, chunk_rows, interpret)
+    return out, res, seen, val_overflow
 
 
 def model_fanin_split(store, cs, node_map, canonical_lt, local_node,

@@ -806,7 +806,7 @@ def test_cli_json_clean_on_shipped_tree():
     # The fast-path completeness gate's required kernels are present
     # (their absence would have failed the run above).
     assert "dense.merge_repack_step" in names
-    assert "pallas.ingest_scatter_tiles[interpret]" in names
+    assert "pallas.ingest_scatter_planes[interpret]" in names
 
 
 def test_fastpath_completeness_gate_fails_on_missing_kernel():
@@ -815,10 +815,10 @@ def test_fastpath_completeness_gate_fails_on_missing_kernel():
         ["dense.merge_repack_step",
          "parallel.collective_join[member2]"])
     assert [f.rule for f in findings] == ["fastpath-kernel-unregistered"]
-    assert "ingest_scatter_tiles" in findings[0].message
+    assert "ingest_scatter_planes" in findings[0].message
     assert _fastpath_completeness(
         ["dense.merge_repack_step",
-         "pallas.ingest_scatter_tiles[interpret]",
+         "pallas.ingest_scatter_planes[interpret]",
          "parallel.collective_join[member2]"]) == []
 
 
@@ -833,7 +833,7 @@ def test_fastpath_completeness_requires_collective_on_multidevice():
     from crdt_tpu.analysis.cli import _fastpath_completeness
     findings = _fastpath_completeness(
         ["dense.merge_repack_step",
-         "pallas.ingest_scatter_tiles[interpret]"])
+         "pallas.ingest_scatter_planes[interpret]"])
     assert [f.rule for f in findings] == ["fastpath-kernel-unregistered"]
     assert "collective_join" in findings[0].message
 

@@ -24,6 +24,7 @@ from crdt_tpu.ops.dense import (DenseChangeset, DenseStore,
                                 _compact_remap_jit, _gc_purge_jit)
 
 N = 1 << 24          # chip_smoke.py's store
+N27 = 1 << 27        # the YCSB deployment's store (cells/configs)
 R = 8                # its merge_many peer rows
 TILES = 64           # touched tiles in one ingest flush
 HBM_BYTES = 16 * 10 ** 9
@@ -55,17 +56,31 @@ def _store(n, sharding):
         jnp.bool_, jnp.bool_)))
 
 
+def _planes(n, sharding):
+    return pm.SplitStore(*(_sds((n // pm._LANE, pm._LANE), d, sharding)
+                           for d in (jnp.int32, jnp.uint32, jnp.int32,
+                                     jnp.int32, jnp.uint32, jnp.int32,
+                                     jnp.int32, jnp.uint32, jnp.int32)))
+
+
 def _changeset(r, n, sharding):
     return DenseChangeset(*(_sds((r, n), d, sharding) for d in (
         jnp.int64, jnp.int32, jnp.int64, jnp.bool_, jnp.bool_)))
 
 
-def _compile(fn, *args, kernel=True):
-    compiled = jax.jit(fn).lower(*args).compile()
+def _peak(compiled):
+    """Bytes the program holds at once, its arguments included (an
+    upper bound: every buffer counted live for the whole program)."""
     mem = compiled.memory_analysis()
-    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
-            + mem.output_size_in_bytes - mem.alias_size_in_bytes
-            ) < HBM_BYTES
+    return (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+
+
+def _compile(fn, *args, kernel=True):
+    # A jitted fn is lowered as it is, so its donation holds.
+    fn = fn if hasattr(fn, "lower") else jax.jit(fn)
+    compiled = fn.lower(*args).compile()
+    assert _peak(compiled) < HBM_BYTES
     if kernel:
         assert "tpu_custom_call" in compiled.as_text()
     return compiled
@@ -76,10 +91,13 @@ def _scalars(sharding):
             _sds((), jnp.int64, sharding))
 
 
-def test_model_fanin_batch_compiles(one_chip):
-    # DenseCrdt._dispatch_pallas: the one fused merge dispatch.
+@pytest.mark.parametrize("held", ["lanes", "planes"])
+def test_model_fanin_batch_compiles(one_chip, held):
+    # DenseCrdt._dispatch_pallas: the one fused merge dispatch, on the
+    # store as either form holds it.
+    store = _store(N, one_chip) if held == "lanes" else _planes(N, one_chip)
     _compile(lambda *a: pm.model_fanin_batch.__wrapped__(
-        *a, chunk_rows=R), _store(N, one_chip),
+        *a, chunk_rows=R), store,
         _changeset(R, N, one_chip), *_scalars(one_chip))
 
 
@@ -101,18 +119,67 @@ def test_split_fanin_kernels_compile(one_chip, kind):
 
 
 def test_ingest_scatter_tiles_compiles(one_chip):
-    from crdt_tpu.ops.pallas_scatter import _scatter_step
+    # The served flush: the touched-tile kernel on resident planes.
+    from crdt_tpu.ops.pallas_scatter import _planes_jit
     rows = TILES * pm._SB
     lanes = [_sds((rows, pm._LANE), d, one_chip) for d in (
-        jnp.int32, jnp.int64, jnp.int64, jnp.bool_)]
-    _compile(lambda *a: _scatter_step(*a, interpret=False),
-             _store(N, one_chip), _sds((TILES,), jnp.int32, one_chip),
+        jnp.int32, jnp.int64, jnp.int64, jnp.int32)]
+    _compile(_planes_jit(True, False),
+             _planes(N, one_chip), _sds((TILES,), jnp.int32, one_chip),
              *lanes, _sds((1,), jnp.int32, one_chip))
 
 
-def test_gc_purge_compiles(one_chip):
-    _compile(_gc_purge_jit(True, None), _store(N, one_chip),
-             _sds((), jnp.int64, one_chip), kernel=False)
+def test_resident_flush_compiles_in_place_at_2_27(one_chip):
+    # The served store's flush on resident planes (the YCSB config's
+    # 2^27 slots), donated: it must hold less than the donated 1-D
+    # ingest scatter at 2^27 did (7,248,473,600 B).
+    from crdt_tpu.ops.pallas_scatter import _planes_jit
+    rows = TILES * pm._SB
+    lanes = [_sds((rows, pm._LANE), d, one_chip) for d in (
+        jnp.int32, jnp.int64, jnp.int64, jnp.int32)]
+    compiled = _planes_jit(True, False).lower(
+        _planes(N27, one_chip), _sds((TILES,), jnp.int32, one_chip),
+        *lanes, _sds((1,), jnp.int32, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert peak < 7_248_473_600
+    assert mem.alias_size_in_bytes >= 9 * 4 << 27
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("to", ["lanes", "planes"])
+def test_store_moves_between_forms_at_2_27(one_chip, to):
+    # A cold path moves the resident planes to lanes, and the next
+    # flush or point read moves them back. The model drops the old
+    # form, so a move is the one time it holds the store twice: the
+    # planes (36 B a slot) and the lanes (34 B a slot), plus the
+    # move's own scratch, well inside HBM at the YCSB size.
+    from crdt_tpu.ops import pallas_scatter as ps
+    if to == "lanes":
+        fn, arg = ps._from_planes_jit, _planes(N27, one_chip)
+    else:
+        fn, arg = ps._to_planes_jit, _store(N27, one_chip)
+    peak = _peak(_compile(fn, arg, kernel=False))
+    assert peak <= (36 + 34) * N27 + (1 << 30)
+
+
+def test_point_read_compiles_at_2_27(one_chip):
+    from crdt_tpu.ops.pallas_scatter import _gather_jit
+    compiled = _gather_jit.lower(_planes(N27, one_chip),
+                                 _sds((), jnp.int32, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    # One slot's words out; nothing store-sized besides the planes.
+    assert mem.output_size_in_bytes <= 4096
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("n", [N, N27])
+def test_gc_purge_compiles(one_chip, n):
+    # Donated, on lanes alone (a cold path drops the planes first).
+    compiled = _compile(_gc_purge_jit(True, None), _store(n, one_chip),
+                        _sds((), jnp.int64, one_chip), kernel=False)
+    assert compiled.memory_analysis().alias_size_in_bytes >= 34 * n
 
 
 def test_compact_remap_compiles(one_chip):

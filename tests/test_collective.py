@@ -135,6 +135,47 @@ def test_collective_join_is_one_dispatch_and_zero_pack_bytes():
         assert len(m._pack_cache) == 1
 
 
+def test_collective_join_over_resident_planes_matches_xla():
+    # Members holding resident split planes (ops/pallas_scatter.py)
+    # join bit for bit like lane-held members, and every member's
+    # store stays on its own mesh device through the join and the
+    # commits and point reads after it.
+    from crdt_tpu.ops.pallas_merge import TILE
+
+    def build(executor):
+        rng = random.Random(9)
+        reps = [DenseCrdt(nm, TILE, wall_clock=FakeClock(start=BASE),
+                          executor=executor) for nm in "abc"]
+        for c in reps:
+            slots = rng.sample(range(TILE), 40)
+            with c.ingest():
+                c.put_batch(slots, [rng.randrange(1, 10_000)
+                                    for _ in slots])
+                c.delete_batch(slots[:3])
+        return reps
+
+    planes, ref = build("pallas-interpret"), build("xla")
+    assert all(m._planes is not None for m in planes)
+    assert all(m._planes is None for m in ref)
+    groups = [CollectiveGroup(planes), CollectiveGroup(ref)]
+    for rnd in range(2):
+        for g in groups:
+            g.join()
+        for i, m in enumerate(planes):
+            dev = groups[0].mesh.devices.flat[i]
+            assert m.get(TILE - 1) == ref[i].get(TILE - 1)
+            assert m._planes is not None
+            assert {d for p in m._planes for d in p.devices()} == {dev}
+        _assert_bit_identical(ref, planes)
+        for reps in (planes, ref):
+            for j, m in enumerate(reps):
+                with m.ingest():
+                    m.put_batch([TILE - 1 - j], [10 * rnd + j])
+        for i, m in enumerate(planes):
+            dev = groups[0].mesh.devices.flat[i]
+            assert {d for p in m._planes for d in p.devices()} == {dev}
+
+
 def test_second_join_is_idempotent():
     coll = _build_replicas(seed=6)
     group = CollectiveGroup(coll)

@@ -281,7 +281,7 @@ def test_pallas_interpret_ingest_flush_matches_xla():
                   executor="pallas-interpret")
     b = DenseCrdt("i", n_slots=TILE, wall_clock=FakeClock(start=BASE),
                   executor="xla")
-    assert a._use_pallas_scatter() and not b._use_pallas_scatter()
+    assert a._resident_for(TILE) and not b._resident_for(TILE)
     for c in (a, b):
         with c.ingest() as wc:
             c.put_batch([0, 1, TILE - 1], [10, 11, 12])
