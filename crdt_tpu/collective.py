@@ -12,11 +12,12 @@ of the same deltas.
 
 One ``join()`` is one device dispatch. Everything the pairwise relay
 path gets from `merge_and_repack` rides the same program: per-member
-``mod`` stamps, the next round's repack masks (pack caches are
-pre-seeded under each member's pre-join watermark), and the post-join
-digest-tree levels (digest caches are pre-seeded too) — so a
-follow-up cross-pod socket round packs and walks from warm caches
-without dispatching anything.
+``mod`` stamps, the next round's repack rows, compacted on the device
+(pack caches are pre-seeded under each member's pre-join watermark),
+and the post-join digest-tree levels, left on the device until a walk
+asks (digest caches are pre-seeded too) — so a follow-up cross-pod
+socket round packs and walks from warm caches without dispatching
+anything, and a round moves kilobytes to the host, not the stores.
 
 Group membership is declared at construction, optionally with the
 ``"host:port"`` addresses the routing layer speaks (`routing.py`),
@@ -34,8 +35,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from .hlc import Hlc
-from .ops.digest import build_digest_tree
-from .parallel.collective import (MEMBER_AXIS, make_collective_join,
+from .obs.registry import default_registry
+from .ops.digest import DeviceDigestLevels
+from .parallel.collective import (MEMBER_AXIS, REPACK_ROWS,
+                                  make_collective_join,
                                   make_collective_mesh)
 
 
@@ -157,7 +160,10 @@ class CollectiveGroup:
     def _check_semantics(self) -> bool:
         """All members must govern every slot by the same lattice
         before lanes may join — the collective twin of the packed
-        wire's tag-mismatch rejection."""
+        wire's tag-mismatch rejection. Untyped members all agree: no
+        tag column to compare."""
+        if all(m._sem is None for m in self.members):
+            return False
         sems = [m._sem_host() for m in self.members]
         ref = sems[0]
         for m, sem in zip(self.members[1:], sems[1:]):
@@ -179,7 +185,17 @@ class CollectiveGroup:
         run the single-dispatch lattice join, land every member on the
         joined store with its canonical clock, digest cache and (when
         ``seed_packs``) pack cache pre-seeded — the `merge_and_repack`
-        contract, amortized over the whole group in one program."""
+        contract, amortized over the whole group in one program.
+
+        What moves to the host is small and does not grow with
+        ``n_slots``: the members' adopted and repack counts, the
+        clock, the digest root and (when ``seed_packs``) each member's
+        compacted repack rows, at most `REPACK_ROWS` a member, in one
+        ``device_get``. A member past that bound is packed from its
+        whole lanes. The digest levels stay on the device until a walk
+        asks (`DeviceDigestLevels`). Bytes fetched, rows adopted and
+        whole-lane pack fallbacks are counted in
+        ``crdt_tpu_collective_*_total``."""
         from .obs.trace import round_id, span, tracer
         members = self.members
         for m in members:
@@ -199,8 +215,9 @@ class CollectiveGroup:
         # CPU ignores donation (with a warning per call); only donate
         # when every member's snapshot is donatable on this backend.
         donate = all(m._donate_writes() for m in members)
+        k = REPACK_ROWS
         step = make_collective_join(self.mesh, has_sem, leaf_width,
-                                    donate=donate)
+                                    donate=donate, repack_rows=k)
 
         node = str(members[0].node_id)
         rid = {"rid": round_id(node)} if tracer().enabled else {}
@@ -211,20 +228,21 @@ class CollectiveGroup:
             args = ((members[0]._sem_device(),) if has_sem else ())
             new_stores, res = step(stores, *args, since, me,
                                    canonical_in)
-
-            # ONE batched fetch: masks + replicated lanes + clock.
-            # mod lanes stay device-only, as everywhere else.
-            win_h, repack_h, lanes_h, canonical = jax.device_get(
-                (res.win, res.repack,
-                 [(s.lt, s.node, s.val, s.tomb) for s in new_stores],
-                 res.new_canonical))
-            win_h = win_h.reshape(len(members), -1)
-            repack_h = repack_h.reshape(len(members), -1)
+            with span("collective.fetch", kind="sync", node=node):
+                # ONE batched fetch of what the host needs; the rows
+                # only when they seed pack caches.
+                want = (res.win_count, res.repack_count,
+                        res.new_canonical, res.levels[0],
+                        tuple(res.rows) if seed_packs else ())
+                got = jax.device_get(want)
+                fetched = sum(np.asarray(a).nbytes
+                              for a in jax.tree_util.tree_leaves(got))
+            win_h, repack_h, canonical, root, rows_h = got
             canonical = int(canonical)
-            tree = build_digest_tree(members[0].n_slots, leaf_width,
-                                     res.levels)
+            levels = DeviceDigestLevels(members[0].n_slots, leaf_width,
+                                        res.levels)
 
-        win_counts = []
+        fallbacks = 0
         for i, m in enumerate(members):
             m._store = new_stores[i]        # setter clears both caches
             m._store_escaped = False
@@ -233,29 +251,62 @@ class CollectiveGroup:
             m._canonical_time = Hlc.from_logical_time(canonical,
                                                       m.node_id)
             m._digest_cache = ((canonical, m._sem_version,
-                                m._store_gen), tree)
+                                m._store_gen), levels)
             m.stats.merges += 1
-            win_counts.append(int(win_h[i].sum()))
-            if seed_packs:
-                self._seed_pack(m, watermarks[i], repack_h[i],
-                                *lanes_h[i], canonical, has_sem)
+        if seed_packs:
+            with span("collective.seed_pack", kind="pack", node=node):
+                for i, m in enumerate(members):
+                    count = int(repack_h[i])
+                    if count <= k:
+                        slots, *cols = (lane[i * k:i * k + count]
+                                        for lane in rows_h)
+                        mask = np.ones(count, bool)
+                    else:
+                        # Past the bound: the member's delta mask and
+                        # whole lanes, as an uncached pack_since.
+                        st = m._store
+                        mask, *cols = jax.device_get(
+                            (m._delta_mask(watermarks[i]), st.lt,
+                             st.node, st.val, st.tomb))
+                        slots = None
+                        fetched += mask.nbytes + sum(c.nbytes
+                                                     for c in cols)
+                        fallbacks += 1
+                    self._seed_pack(m, watermarks[i], mask, *cols,
+                                    canonical, has_sem, slots=slots)
+        reg = default_registry()
+        reg.counter("crdt_tpu_collective_join_total",
+                    "collective join rounds").inc(node=node)
+        reg.counter("crdt_tpu_collective_fetch_bytes_total",
+                    "bytes collective joins fetched from the devices "
+                    "to the host").inc(fetched, node=node)
+        reg.counter("crdt_tpu_collective_adopted_rows_total",
+                    "rows members adopted in collective joins").inc(
+                        int(win_h.sum()), node=node)
+        if fallbacks:
+            reg.counter("crdt_tpu_collective_pack_fallback_total",
+                        "members whose repack rows passed the "
+                        "collective join's bound, packed from whole "
+                        "lanes").inc(fallbacks, node=node)
         return CollectiveJoinReport(
-            new_canonical=canonical, win_counts=win_counts,
-            digest_root=tree.root, members=len(members))
+            new_canonical=canonical,
+            win_counts=[int(c) for c in win_h],
+            digest_root=int(root[0]), members=len(members))
 
     @staticmethod
     def _seed_pack(m, watermark: Hlc, mask, lt, node, val, tomb,
-                   canonical: int, has_sem: bool) -> None:
+                   canonical: int, has_sem: bool,
+                   slots: Optional[np.ndarray] = None) -> None:
         """Seed the member's pack cache under its pre-join watermark —
         the exact key the next watermark-aligned `pack_since` (a
         cross-pod peer resuming delta sync) presents. Host-side
         column select only (`_pack_host_columns`): no wire stage runs,
-        so ``crdt_tpu_pack_copy_bytes_total`` does not move."""
+        so ``crdt_tpu_pack_copy_bytes_total`` does not move. With
+        ``slots`` the columns are the member's compacted repack rows
+        and these their slots; else whole host lanes."""
         resolved = m._resolve_sem_mode("include" if has_sem else "auto")
-        # The lanes arrive as numpy rows of join()'s one batched
-        # device_get — column select only, no further copy.
         packed = m._pack_host_columns(mask, lt, node, val, tomb,
-                                      resolved)
+                                      resolved, slots=slots)
         key = (watermark.logical_time, canonical, m._sem_version,
                m._store_gen, resolved, None)
         m._pack_cache_store(key, (packed, m._table.ids()))

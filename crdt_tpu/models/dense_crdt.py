@@ -606,9 +606,20 @@ class DenseCrdt:
                     np.broadcast_to(np.asarray(tombs, bool),
                                     slots.shape)))
             return
-        slots = jnp.asarray(slots)
-        values = jnp.asarray(values, jnp.int64)
-        tombs_h = None if tombs is None else np.asarray(tombs, bool)
+        k = slots.shape[0]
+        values = np.broadcast_to(np.asarray(values, np.int64), (k,))
+        tombs_h = None if tombs is None else np.broadcast_to(
+            np.asarray(tombs, bool), (k,))
+        # Lanes padded to a power of two with n_slots sentinels the
+        # scatter drops: batch sizes share O(log k) compiled programs.
+        width = 1 << max(k - 1, 0).bit_length()
+        slot_l = np.full(width, self.n_slots, np.int32)
+        val_l = np.zeros(width, np.int64)
+        tomb_l = np.zeros(width, bool)
+        slot_l[:k] = slots
+        val_l[:k] = values
+        if tombs_h is not None:
+            tomb_l[:k] = tombs_h
         self._canonical_time = Hlc.send(self._canonical_time,
                                         millis=self._wall_clock())
         t = jnp.int64(self._canonical_time.logical_time)
@@ -617,12 +628,12 @@ class DenseCrdt:
         # backends that support it so an O(k) write never copies the
         # O(n_slots) store.
         self._store = put_scatter(
-            self._store, slots, values,
-            t, me, tombs=None if tombs_h is None else jnp.asarray(tombs_h),
+            self._store, jnp.asarray(slot_l), jnp.asarray(val_l),
+            t, me, tombs=jnp.asarray(tomb_l),
             donate=self._donate_writes(), sharding=self._write_sharding())
         self._store_escaped = False
         self.stats.puts += 1
-        self.stats.records_put += int(slots.shape[0])
+        self.stats.records_put += int(k)
         self._emit_put(slots, values, tombs_h)
 
     def delete_batch(self, slots) -> None:
@@ -2681,16 +2692,21 @@ class DenseCrdt:
 
     def _pack_host_columns(self, mask: np.ndarray, lt: np.ndarray,
                            node: np.ndarray, val: np.ndarray,
-                           tomb: np.ndarray,
-                           resolved: str) -> PackedDelta:
+                           tomb: np.ndarray, resolved: str,
+                           slots: Optional[np.ndarray] = None
+                           ) -> PackedDelta:
         """Select the masked rows and land them in ONE arena
         (`ops.packing.pack_into_arena`) — the zero-copy pack tail
-        shared by `pack_since` and `merge_and_repack`. The arena's
-        views are the exact buffers `pack_rows` frames for the wire."""
+        shared by `pack_since`, `merge_and_repack` and the collective
+        join. The arena's views are the exact buffers `pack_rows`
+        frames for the wire. ``slots`` given: the columns are rows
+        already selected, in slot order, and ``slots`` their slot
+        numbers; the pack is the one the whole lanes would give."""
         idx = np.nonzero(mask)[0]
+        slot_of = idx if slots is None else slots[idx]
         sem_src = None
         if resolved == "withhold":
-            typed = self._sem[idx] != 0
+            typed = self._sem[slot_of] != 0
             withheld = int(typed.sum())
             if withheld:
                 from ..obs.registry import default_registry
@@ -2702,8 +2718,9 @@ class DenseCrdt:
                                         node=str(self._node_id))
                 idx = idx[~typed]
         elif resolved == "include":
-            sem_src = self._sem
-        return pack_into_arena(idx, lt, node, val, tomb, sem=sem_src)
+            sem_src = self._sem if slots is None else self._sem[slots]
+        return pack_into_arena(idx, lt, node, val, tomb, sem=sem_src,
+                               slot_ids=slots)
 
     def _pack_cache_store(self, key, out) -> None:
         """Insert a finished pack, LRU-evicting past PACK_CACHE_SLOTS
@@ -2761,7 +2778,7 @@ class DenseCrdt:
         Lookups are counted in ``crdt_tpu_digest_cache_total``."""
         from ..obs.registry import default_registry
         from ..obs.trace import span
-        from ..ops.digest import build_digest_tree
+        from ..ops.digest import DeviceDigestLevels, build_digest_tree
         # Drain BEFORE the key reads the canonical clock — same
         # aliasing hazard as pack_since.
         self.drain_ingest()
@@ -2771,8 +2788,13 @@ class DenseCrdt:
             "digest_tree cache lookups by outcome")
         cached = self._digest_cache
         if cached is not None and cached[0] == key:
+            tree = cached[1]
+            if isinstance(tree, DeviceDigestLevels):
+                # A collective join left the levels on the device.
+                tree = tree.tree()
+                self._digest_cache = (key, tree)
             counter.inc(outcome="hit", node=str(self._node_id))
-            return cached[1]
+            return tree
         counter.inc(outcome="miss", node=str(self._node_id))
         with span("digest_tree", kind="digest",
                   hlc=lambda: self._canonical_time,

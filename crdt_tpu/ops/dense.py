@@ -573,15 +573,17 @@ import functools as _functools
 # model paying a full-store re-shard copy afterwards.
 @_functools.lru_cache(maxsize=None)
 def _put_scatter(donate: bool, sharding=None):
+    # mode="drop": `DenseCrdt.put_batch` pads the batch to a power of
+    # two with slot == n_slots sentinels (stable jit shapes).
     def step(store: DenseStore, slots, values, tombs, t, me) -> DenseStore:
         out = DenseStore(
-            lt=store.lt.at[slots].set(t),
-            node=store.node.at[slots].set(me),
-            val=store.val.at[slots].set(values),
-            mod_lt=store.mod_lt.at[slots].set(t),
-            mod_node=store.mod_node.at[slots].set(me),
-            occupied=store.occupied.at[slots].set(True),
-            tomb=store.tomb.at[slots].set(tombs),
+            lt=store.lt.at[slots].set(t, mode="drop"),
+            node=store.node.at[slots].set(me, mode="drop"),
+            val=store.val.at[slots].set(values, mode="drop"),
+            mod_lt=store.mod_lt.at[slots].set(t, mode="drop"),
+            mod_node=store.mod_node.at[slots].set(me, mode="drop"),
+            occupied=store.occupied.at[slots].set(True, mode="drop"),
+            tomb=store.tomb.at[slots].set(tombs, mode="drop"),
         )
         if sharding is not None:
             out = jax.lax.with_sharding_constraint(out, sharding)

@@ -115,14 +115,20 @@ def fold_leaves(digests, leaf_width: int):
     an all-empty leaf digest identically (0 contribution). Emits
     ``ceil(n / leaf_width)`` leaves — NO power-of-two padding here, so
     per-shard folds concatenate into the exact global leaf row
-    (`parallel.make_sharded_digest`)."""
+    (`parallel.make_sharded_digest`).
+
+    A 1-D reduce window, not a sum over a ``[n_leaves, leaf_width]``
+    reshape: the TPU pads that array's minor dimension of 8 to 128
+    lanes, a 16x copy of the digests (16.5 GB at 2^27 slots, past the
+    chip's memory)."""
     n = digests.shape[0]
     n_leaves = max(1, -(-n // leaf_width))
     pad = n_leaves * leaf_width - n
     if pad:
         digests = jnp.concatenate(
             [digests, jnp.zeros((pad,), jnp.uint64)])
-    return jnp.sum(digests.reshape(n_leaves, leaf_width), axis=1)
+    return jax.lax.reduce_window(digests, np.uint64(0), jax.lax.add,
+                                 (leaf_width,), (leaf_width,), "VALID")
 
 
 def combine_level(children):
@@ -250,6 +256,30 @@ def build_digest_tree(n_slots: int, leaf_width: int,
     host = jax.device_get(tuple(levels))
     return DigestTree(n_slots=int(n_slots), leaf_width=int(leaf_width),
                       levels=tuple(np.asarray(a) for a in host))
+
+
+class DeviceDigestLevels:
+    """Digest levels a program left on the device (the collective
+    join's): `tree` fetches every level in one ``device_get`` the first
+    time a walk or ``digest_tree`` asks, and keeps the host tree, so a
+    round that nobody walks moves no digest bytes to the host. Members
+    of one group share one instance, so the group fetches once."""
+
+    __slots__ = ("n_slots", "leaf_width", "_levels", "_tree")
+
+    def __init__(self, n_slots: int, leaf_width: int,
+                 levels: Sequence[jax.Array]):
+        self.n_slots = n_slots
+        self.leaf_width = leaf_width
+        self._levels = tuple(levels)
+        self._tree: Optional[DigestTree] = None
+
+    def tree(self) -> DigestTree:
+        if self._tree is None:
+            self._tree = build_digest_tree(self.n_slots, self.leaf_width,
+                                           self._levels)
+            self._levels = ()
+        return self._tree
 
 
 def walk_divergent_leaves(

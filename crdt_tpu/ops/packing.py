@@ -165,7 +165,9 @@ def arena_of(lane: np.ndarray):
 
 def pack_into_arena(idx: np.ndarray, lt: np.ndarray, node: np.ndarray,
                     val: np.ndarray, tomb: np.ndarray,
-                    sem: Optional[np.ndarray] = None) -> "PackedDelta":
+                    sem: Optional[np.ndarray] = None,
+                    slot_ids: Optional[np.ndarray] = None
+                    ) -> "PackedDelta":
     """Gather the rows selected by ``idx`` out of host store columns
     straight into ONE preallocated arena; the returned delta's lanes
     are aligned views into it, already in the exact wire dtypes
@@ -182,7 +184,11 @@ def pack_into_arena(idx: np.ndarray, lt: np.ndarray, node: np.ndarray,
     Ownership: the arena belongs to the returned delta and is NEVER
     reused or resized — an evicted pack-cache entry may still be
     referenced by an in-flight send, so recycling arenas would
-    corrupt frames already on the wire (docs/FASTPATH.md)."""
+    corrupt frames already on the wire (docs/FASTPATH.md).
+
+    ``slot_ids``: the columns are rows already selected (the collective
+    join's compacted repack rows), ``idx`` picks among them and
+    ``slot_ids[idx]`` are their slots; else ``idx`` are the slots."""
     specs = [("slots", np.dtype(np.int32)),
              ("lt", np.dtype(np.int64)),
              ("node", np.dtype(np.int32)),
@@ -200,7 +206,7 @@ def pack_into_arena(idx: np.ndarray, lt: np.ndarray, node: np.ndarray,
     arena = np.empty(total, np.uint8)
     views = {name: arena[off:off + k * dt.itemsize].view(dt)
              for (name, dt), off in zip(specs, offs)}
-    views["slots"][:] = idx             # intp → int32 cast-assign
+    views["slots"][:] = idx if slot_ids is None else slot_ids[idx]
     np.take(lt, idx, out=views["lt"])
     np.take(node, idx, out=views["node"])
     np.take(val, idx, out=views["val"])

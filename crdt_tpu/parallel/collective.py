@@ -28,9 +28,13 @@ rules the pairwise kernels apply:
 The post-join digest-tree leaves are computed in the SAME program
 (the joined lanes are replicated across members by construction, so
 each member digests its own output block and the levels come out
-``P()``), and per-member ``mod`` stamps + repack masks ride along —
-one dispatch yields everything `CollectiveGroup.join` needs to
-pre-seed the pack and digest caches exactly like `merge_and_repack`.
+``P()``), and per-member ``mod`` stamps ride along. So do what the
+host needs to seed each member's pack cache, already small: the
+member's adopted-row count, its repack-row count, and its repack rows
+compacted on the device (`compact_rows`, at most ``repack_rows`` a
+member, in slot order) — one dispatch yields everything
+`CollectiveGroup.join` needs, and what it fetches does not grow with
+the store.
 
 On a real pod the member axis rides ICI; on the 1-core virtual mesh
 (tests, `bench.py --mode collective`) the same program runs across
@@ -64,10 +68,31 @@ from .fanin import (Mesh, NamedSharding, P, _BIG_RANK, _make_mesh,
 MEMBER_AXIS = "member"
 
 
+#: Repack rows the join compacts on the device per member: a steady
+#: round re-packs the member's own last batch plus the rows it adopted,
+#: far below this. A member past it is packed from its whole lanes
+#: (`CollectiveGroup.join`'s counted fallback).
+REPACK_ROWS = 1 << 15
+#: Slots per block of `compact_rows`' first level.
+_COMPACT_BLOCK = 64
+
+
+class RepackRows(NamedTuple):
+    """Each member's repack rows, compacted: ``[G*K]`` lanes, member
+    ``i``'s ``K`` rows at ``[i*K, (i+1)*K)`` in slot order; a row past
+    the member's ``repack_count`` holds slot ``n_slots``."""
+    slots: jax.Array                # int32
+    lt: jax.Array                   # int64
+    node: jax.Array                 # int32
+    val: jax.Array                  # int64
+    tomb: jax.Array                 # bool
+
+
 class CollectiveJoinResult(NamedTuple):
     new_canonical: jax.Array        # int64 scalar, replicated
-    win: jax.Array                  # bool[G*N] per-member changed mask
-    repack: jax.Array               # bool[G*N] mod_lt >= since mask
+    win_count: jax.Array            # int32[G] rows each member adopted
+    repack_count: jax.Array         # int32[G] rows each member re-packs
+    rows: RepackRows                # the repack rows, compacted
     levels: Tuple[jax.Array, ...]   # post-join digest levels, root-first
 
 
@@ -116,8 +141,34 @@ def _typed_group_val(sem, occ, l_lt, l_val, m1, w_val):
     return out
 
 
-def _join_block(leaf_width: int, has_sem: bool, store: DenseStore,
-                *rest):
+def compact_rows(mask, k: int):
+    """The first ``k`` set positions of ``mask`` in ascending order
+    (``n`` past the count), and the count, as int32. Two levels and no
+    scatter: block counts and their running sums over ``n / 64``
+    blocks, a binary search of those sums for each output row's block,
+    then the row's rank within its 64 slots — no prefix sum over all
+    ``n`` slots."""
+    n = mask.shape[0]
+    b = min(_COMPACT_BLOCK, n)
+    nb = -(-n // b)
+    if nb * b != n:
+        mask = jnp.concatenate([mask, jnp.zeros(nb * b - n, bool)])
+    blocks = mask.reshape(nb, b)
+    counts = jnp.sum(blocks, axis=1, dtype=jnp.int32)
+    ends = jnp.cumsum(counts)
+    j = jnp.arange(k, dtype=jnp.int32)
+    # Row j lies in the first block whose running count exceeds j, at
+    # rank j - (set bits before that block) within it.
+    blk = jnp.minimum(jnp.searchsorted(ends, j, side="right"), nb - 1)
+    rank = j - (ends[blk] - counts[blk])
+    within = jnp.cumsum(blocks[blk], axis=1, dtype=jnp.int32)
+    pos = blk * b + jnp.sum(within <= rank[:, None], axis=1,
+                            dtype=jnp.int32)
+    return jnp.where(j < ends[-1], pos, n), ends[-1]
+
+
+def _join_block(leaf_width: int, has_sem: bool, repack_rows: int,
+                store: DenseStore, *rest):
     """Per-member body under shard_map: lanes arrive as the member's
     own [N] block of the [G*N] group lanes, scalars per member arrive
     [1]; ``sem`` (when present) and ``canonical_in`` are replicated."""
@@ -178,6 +229,13 @@ def _join_block(leaf_width: int, has_sem: bool, store: DenseStore,
     mod_lt_out = jnp.where(win, new_canonical, mod_lt)
     mod_node_out = jnp.where(win, me_i, mod_node)
     repack = occ_out & (mod_lt_out >= since_i)
+    # The rows the member's next watermark-aligned pack ships, gathered
+    # here so the host fetches K rows instead of N-slot lanes.
+    pos, repack_count = compact_rows(repack, repack_rows)
+    at = jnp.minimum(pos, lt.shape[0] - 1)
+    rows = RepackRows(slots=pos, lt=lt_out[at], node=node_out[at],
+                      val=val_out[at], tomb=tomb_out[at])
+    win_count = jnp.sum(win, dtype=jnp.int32)
 
     # Post-join digest leaves in the SAME program: the replicated
     # lanes are identical across members by construction, so every
@@ -189,7 +247,8 @@ def _join_block(leaf_width: int, has_sem: bool, store: DenseStore,
     new_store = DenseStore(
         lt=lt_out, node=node_out, val=val_out, mod_lt=mod_lt_out,
         mod_node=mod_node_out, occupied=occ_out, tomb=tomb_out)
-    return (new_store, win, repack, new_canonical, leaves)
+    return (new_store, win_count[None], repack_count[None], rows,
+            new_canonical, leaves)
 
 
 def _group_lanes(stores, mesh: Mesh) -> DenseStore:
@@ -218,7 +277,8 @@ def _member_blocks(lane: jax.Array) -> list:
 
 @functools.lru_cache(maxsize=None)
 def make_collective_join(mesh: Mesh, has_sem: bool, leaf_width: int,
-                         donate: bool = False):
+                         donate: bool = False,
+                         repack_rows: int = REPACK_ROWS):
     """Build the jitted single-dispatch collective join for a member
     mesh.
 
@@ -230,7 +290,9 @@ def make_collective_join(mesh: Mesh, has_sem: bool, leaf_width: int,
     ``canonical_in`` is the max of the members' pre-join canonical
     lts. Member ``i``'s store joins on, and comes back on, the mesh's
     ``i``-th device: one whole replica per device. Replicated lanes
-    are identical across members, ``mod`` lanes are per-member.
+    are identical across members, ``mod`` lanes are per-member. Each
+    member's repack rows come back compacted, ``repack_rows`` a
+    member (`RepackRows`).
     ``donate=True`` consumes the input store buffers (gate it off on
     CPU, where XLA ignores donation with a warning).
 
@@ -242,17 +304,21 @@ def make_collective_join(mesh: Mesh, has_sem: bool, leaf_width: int,
     in_specs = ((store_spec,)
                 + ((P(),) if has_sem else ())
                 + (P(MEMBER_AXIS), P(MEMBER_AXIS), P()))
+    member = P(MEMBER_AXIS)
     join = _shard_map(
-        functools.partial(_join_block, leaf_width, has_sem),
+        functools.partial(_join_block, leaf_width, has_sem, repack_rows),
         mesh=mesh, in_specs=in_specs,
-        out_specs=(store_spec, P(MEMBER_AXIS), P(MEMBER_AXIS),
+        out_specs=(store_spec, member, member,
+                   RepackRows(*([member] * len(RepackRows._fields))),
                    P(), P()),
         check_vma=False)
 
     def _step(group, *args):
-        out_store, win, repack, canonical, leaves = join(group, *args)
+        out_store, win_count, repack_count, rows, canonical, leaves = \
+            join(group, *args)
         return out_store, CollectiveJoinResult(
-            new_canonical=canonical, win=win, repack=repack,
+            new_canonical=canonical, win_count=win_count,
+            repack_count=repack_count, rows=rows,
             levels=tree_levels_from_leaves(leaves))
 
     jitted = jax.jit(_step, donate_argnums=(0,) if donate else ())

@@ -10,6 +10,7 @@ never at import — so every xdist worker collects the same tests and
 only the worker running this file loads the TPU compiler.
 """
 
+import functools
 import os
 
 import jax
@@ -205,7 +206,8 @@ def test_sharded_pallas_fanin_compiles_on_2x2(topo):
     assert "all-reduce" in compiled.as_text()
 
 
-def test_collective_join_compiles_on_2x2(topo):
+@pytest.mark.parametrize("n", [N, N27])
+def test_collective_join_compiles_on_2x2(topo, n):
     from crdt_tpu.parallel.collective import (MEMBER_AXIS,
                                               make_collective_join)
     from crdt_tpu.models.dense_crdt import DenseCrdt
@@ -214,7 +216,20 @@ def test_collective_join_compiles_on_2x2(topo):
                                 donate=True)
     rep, mem = NamedSharding(mesh, P()), NamedSharding(mesh,
                                                        P(MEMBER_AXIS))
-    # One whole 16M-slot member per chip: [4*N] lanes over the members.
-    _compile(step.jitted, _store(4 * N, mem), _sds((4,), jnp.int64, mem),
-             _sds((4,), jnp.int32, mem), _sds((), jnp.int64, rep),
-             kernel=False)
+    # One whole member per chip: [4*n] lanes over the members; at 2^27
+    # the pod4.join cell's members.
+    compiled = _compile(step.jitted, _store(4 * n, mem),
+                        _sds((4,), jnp.int64, mem),
+                        _sds((4,), jnp.int32, mem),
+                        _sds((), jnp.int64, rep), kernel=False)
+    assert "all-reduce" in compiled.as_text()
+
+
+def test_digest_tree_compiles_at_2_27(one_chip):
+    # The leaf fold once padded a [n/8, 8] u64 reshape to 128 lanes
+    # (16.5 GB at 2^27); the 1-D window fold keeps a 2^27-slot
+    # store's digest within the chip.
+    from crdt_tpu.ops.digest import _digest_tree_jit
+    lane = functools.partial(_sds, (N27,), sharding=one_chip)
+    _compile(_digest_tree_jit(8, False), lane(jnp.int64), lane(jnp.int64),
+             lane(jnp.bool_), lane(jnp.bool_), kernel=False)

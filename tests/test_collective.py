@@ -15,9 +15,13 @@ import jax
 import numpy as np
 import pytest
 
-from crdt_tpu import DenseCrdt, GossipNode, default_registry
+from cells import ycsb
+from cells.reference import pod_fold
+from crdt_tpu import DenseCrdt, GossipNode, collective, default_registry
 from crdt_tpu.collective import CollectiveGroup
 from crdt_tpu.obs.device import default_ledger
+from crdt_tpu.ops.packing import PackedDelta
+from crdt_tpu.parallel.collective import REPACK_ROWS, compact_rows
 from crdt_tpu.sync import sync_collective, sync_packed
 from crdt_tpu.testing import FakeClock
 
@@ -217,6 +221,142 @@ def test_sync_collective_wraps_group_join():
     assert report.adopted > 0
     roots = {m.digest_tree().root for m in coll}
     assert len(roots) == 1
+
+
+# --- the pod deployment against its plain fold ---
+
+POD_SLOTS = 8192
+
+
+def _counter_total(name):
+    return sum(s["value"] for s in
+               default_registry().counter(name).samples())
+
+
+def _pod(n_slots, seed=0):
+    """Four members, each on its own clock starting at the same
+    millisecond (so equal stamps meet on hot keys), loaded with one
+    seeded record in every slot by member m0, as the pod4.join
+    cell's members are."""
+    names = [f"m{i}" for i in range(4)]
+    load = DenseCrdt("m0", n_slots, wall_clock=FakeClock(start=BASE - 9))
+    slots = np.arange(n_slots)
+    load.put_batch(slots, pod_fold.loaded_value(slots, seed))
+    base_lt = load.canonical_time.logical_time
+    members = [DenseCrdt(nm, n_slots, store=load.store, node_ids=names,
+                         wall_clock=FakeClock(start=BASE))
+               for nm in names]
+    return members, base_lt
+
+
+def _zipf_rounds(group, rounds, seed, log, n_keys=POD_SLOTS):
+    for r in range(rounds):
+        for i, m in enumerate(group.members):
+            rng = np.random.default_rng([seed, r, i])
+            slots = np.unique(ycsb.scrambled_zipfian(rng, 256, n_keys))
+            vals = rng.integers(-(1 << 62), 1 << 62, len(slots))
+            m.put_batch(slots, vals)
+            log.append((i, m.canonical_time.logical_time, slots, vals))
+        group.join()
+
+
+def test_pod_rounds_match_the_plain_fold():
+    members, base_lt = _pod(POD_SLOTS, seed=3)
+    group = CollectiveGroup(members)
+    log = []
+    _zipf_rounds(group, 3, seed=3, log=log)
+    stamps = {}
+    for rank, lt, slots, _ in log:
+        for s in slots:
+            stamps.setdefault(int(s), set()).add((lt, rank))
+    # The hot keys conflict, and across members under equal stamps.
+    assert any(len({lt for lt, _ in v}) < len(v) for v in stamps.values())
+    want = pod_fold.fold(log, base_lt, 0, seed=3)
+    lanes = [_lanes(m) for m in members]
+    for got in lanes[1:]:
+        for a, b in zip(lanes[0], got):
+            assert np.array_equal(a, b)
+    lt, node, val, tomb, occ = (x[want["slots"]] for x in lanes[0])
+    got = {"lt": lt, "rank": node, "val": val, "tomb": tomb,
+           "occupied": occ}
+    assert pod_fold.mismatches(got, want) == 0
+    untouched = np.setdiff1d(np.arange(POD_SLOTS), want["slots"])
+    assert np.array_equal(lanes[0][2][untouched],
+                          pod_fold.loaded_value(untouched, 3))
+
+
+@pytest.mark.parametrize("mixed_sem", [False, True])
+@pytest.mark.parametrize("repack_rows", [REPACK_ROWS, 2])
+def test_seeded_pack_is_byte_identical_to_uncached_pack_since(
+        monkeypatch, mixed_sem, repack_rows):
+    # The compacted rows (and, past the bound, the whole-lane
+    # fallback) seed exactly what an uncached pack_since builds.
+    monkeypatch.setattr(collective, "REPACK_ROWS", repack_rows)
+    coll = _build_replicas(seed=11, mixed_sem=mixed_sem)
+    marks = [m.canonical_time for m in coll]
+    CollectiveGroup(coll).join()
+    mode = "include" if mixed_sem else "auto"
+    for m, mark in zip(coll, marks):
+        hits = _counter_total("crdt_tpu_pack_cache_total")
+        seeded, ids = m.pack_since(mark, sem_mode=mode)
+        assert _counter_total("crdt_tpu_pack_cache_total") == hits + 1
+        assert len(seeded.slots) > 0
+        m._pack_cache.clear()
+        fresh, fresh_ids = m.pack_since(mark, sem_mode=mode)
+        assert ids == fresh_ids
+        for lane in PackedDelta._fields:
+            a, b = getattr(seeded, lane), getattr(fresh, lane)
+            assert (a is None) == (b is None), lane
+            if a is not None:
+                assert a.dtype == b.dtype, lane
+                assert a.tobytes() == b.tobytes(), lane
+
+
+def test_join_fetch_bytes_do_not_grow_with_the_store():
+    per_join = []
+    for n in (1 << 13, 1 << 16):
+        members = [DenseCrdt(f"m{i}", n, wall_clock=FakeClock(start=BASE))
+                   for i in range(4)]
+        group = CollectiveGroup(members)
+        _zipf_rounds(group, 1, seed=5, log=[], n_keys=1 << 13)
+        before = _counter_total("crdt_tpu_collective_fetch_bytes_total")
+        joins = _counter_total("crdt_tpu_collective_join_total")
+        _zipf_rounds(group, 1, seed=6, log=[], n_keys=1 << 13)
+        assert _counter_total("crdt_tpu_collective_join_total") == joins + 1
+        per_join.append(_counter_total(
+            "crdt_tpu_collective_fetch_bytes_total") - before)
+    assert per_join[0] == per_join[1] > 0
+
+
+def test_repack_rows_past_the_bound_fall_back_counted(monkeypatch):
+    monkeypatch.setattr(collective, "REPACK_ROWS", 2)
+    coll = _build_replicas(seed=12, mixed_sem=False)
+    group = CollectiveGroup(coll)
+    falls = _counter_total("crdt_tpu_collective_pack_fallback_total")
+    adopted = _counter_total("crdt_tpu_collective_adopted_rows_total")
+    report = group.join()
+    # Every member re-packs its own six rows and what it adopted.
+    assert _counter_total(
+        "crdt_tpu_collective_pack_fallback_total") == falls + 3
+    assert _counter_total(
+        "crdt_tpu_collective_adopted_rows_total") == adopted + \
+        report.adopted > adopted
+    for m in coll:
+        assert len(m._pack_cache) == 1
+
+
+@pytest.mark.parametrize("n,k", [(64, 8), (100, 64), (1 << 12, 300)])
+def test_compact_rows_is_the_first_k_set_positions(n, k):
+    rng = np.random.default_rng(n)
+    for density in (0.0, 0.02, 0.5, 1.0):
+        mask = rng.random(n) < density
+        pos, count = jax.device_get(jax.jit(
+            compact_rows, static_argnums=1)(mask, k))
+        want = np.nonzero(mask)[0]
+        assert int(count) == len(want)
+        head = want[:k]
+        assert np.array_equal(pos[:len(head)], head)
+        assert (pos[len(head):] == n).all()
 
 
 # --- contract surface ---
