@@ -18,6 +18,7 @@ from crdt_tpu import (DenseCrdt, GossipNode, RetryPolicy, SyncServer,
                       sync_merkle, sync_merkle_over_conn)
 from crdt_tpu.gossip import Peer
 from crdt_tpu.obs.registry import default_registry
+from crdt_tpu.ops import digest as digest_ops
 from crdt_tpu.ops.digest import (PREFETCH_LEVELS, coalesce_leaf_ranges,
                                  walk_divergent_leaves)
 from crdt_tpu.sync import _packed_nbytes
@@ -109,6 +110,77 @@ def test_clean_walk_costs_one_round():
     assert leaves == [] and rounds == 1
     assert fetched == sum(
         2 ** l for l in range(min(PREFETCH_LEVELS, t.depth)))
+
+
+def _host_levels(lt, val, tomb, occupied, sem, leaf_width):
+    """The digest tree rebuilt in numpy, root-first: per-slot mix, leaf
+    sums, zero-padded to a power of two, pairwise combines. Uses the
+    module's own mix and salts; u64 array arithmetic wraps as on the
+    device."""
+    d = digest_ops
+    idx = np.arange(lt.shape[0], dtype=np.uint64)
+    h = d._mix64(lt.astype(np.uint64) + d._GOLDEN * (idx + np.uint64(1)))
+    h = h ^ d._mix64(val.astype(np.uint64) ^ d._MIX_B)
+    h = h ^ np.where(tomb, d._TOMB_SALT, np.uint64(0))
+    if sem is not None:
+        h = h ^ d._mix64(sem.astype(np.uint64) + d._SEM_SALT)
+    h = np.where(occupied, d._mix64(h), np.uint64(0))
+    n_leaves = -(-h.shape[0] // leaf_width)
+    h = np.concatenate([h, np.zeros(n_leaves * leaf_width - h.shape[0],
+                                    np.uint64)])
+    leaves = h.reshape(n_leaves, leaf_width).sum(axis=1, dtype=np.uint64)
+    width = 1 << (n_leaves - 1).bit_length()
+    levels = [np.concatenate([leaves, np.zeros(width - n_leaves,
+                                               np.uint64)])]
+    while levels[-1].shape[0] > 1:
+        c = levels[-1]
+        levels.append(d._mix64(c[0::2] + d._GOLDEN * c[1::2] + d._MIX_A))
+    return levels[::-1]
+
+
+@pytest.mark.parametrize("with_sem", [False, True])
+@pytest.mark.parametrize("n_leaves", [1, 2, 3, 1000, 4101])
+def test_digest_levels_match_host_reference(n_leaves, with_sem):
+    # Every level, not just the root: peers walk levels across
+    # versions, so each node's value and position is wire format.
+    import jax.numpy as jnp
+    w = digest_ops.DEFAULT_LEAF_WIDTH
+    n = w * n_leaves - n_leaves % 5          # ragged tail leaf
+    rng = np.random.default_rng([26, n_leaves])
+    lt = rng.integers(-2 ** 63, 2 ** 63 - 1, n, dtype=np.int64)
+    val = rng.integers(-2 ** 63, 2 ** 63 - 1, n, dtype=np.int64)
+    tomb = rng.random(n) < 0.2
+    occupied = rng.random(n) < 0.7
+    sem = (rng.integers(-128, 128, n, dtype=np.int64).astype(np.int8)
+           if with_sem else None)
+    want = _host_levels(lt, val, tomb, occupied, sem, w)
+    args = [jnp.asarray(a) for a in (lt, val, tomb, occupied)]
+    if with_sem:
+        args.append(jnp.asarray(sem))
+    got = digest_ops._digest_tree_jit(w, with_sem)(*args)
+    assert len(got) == len(want)
+    for level, (g, e) in enumerate(zip(got, want)):
+        np.testing.assert_array_equal(np.asarray(g), e,
+                                      err_msg=f"level {level}")
+    # The interior levels alone, from the reference's padded leaves.
+    got = digest_ops.tree_levels_from_leaves(jnp.asarray(want[-1]))
+    for level, (g, e) in enumerate(zip(got, want)):
+        np.testing.assert_array_equal(np.asarray(g), e,
+                                      err_msg=f"level {level}")
+
+
+def test_digest_root_of_seeded_store_is_pinned():
+    # A literal root: every other test compares device trees with
+    # device trees, so a change of tree shape or mix would pass them
+    # all while breaking walks against peers of other versions.
+    c = _make("pin", 1 << 12)
+    rng = np.random.default_rng(20260)
+    slots = rng.choice(1 << 12, 3000, replace=False)
+    c.put_batch(slots, rng.integers(-2 ** 62, 2 ** 62, 3000))
+    c.delete_batch(slots[:300])
+    tree = c.digest_tree()
+    assert tree.depth == 10
+    assert tree.root == 13733892194618584479
 
 
 # ------------------------------------------------ range pack
