@@ -23,14 +23,14 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))  # repo root (bench.py helpers)
+    os.path.abspath(__file__))))  # repo root
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from bench import make_changeset, _MILLIS
+from changesets import make_changeset, _MILLIS
 from crdt_tpu.hlc import SHIFT
 from crdt_tpu.ops.dense import empty_dense_store
 from crdt_tpu.ops.pallas_merge import (_SB, _LANE, _lex_gt, _split64,
@@ -339,7 +339,7 @@ def _copy_batch_kernel(narrow_val, scalars_ref, *refs):
 def run_batch_copy(n_keys: int, n_rows: int, chunk_rows: int = 16,
                    loops: int = 48, value_width: int = 64,
                    repeats: int = 3) -> float:
-    """`bench_distinct`'s protocol with `pallas_fanin_batch` swapped
+    """The distinct-rows protocol with `pallas_fanin_batch` swapped
     for the same-layout pure-copy kernel: identical narrow lanes,
     tiles, grid, index maps, store aliasing, loop chaining, and fence.
     The merges/s this prints is the HBM ceiling the production

@@ -30,7 +30,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from bench import build_stream_fn, make_changeset, _MILLIS
+from changesets import build_stream_fn, make_changeset, _MILLIS
 from crdt_tpu.hlc import SHIFT
 from crdt_tpu.ops.dense import empty_dense_store, fanin_step
 from crdt_tpu.ops.pallas_merge import (join_store, pallas_fanin_batch,
@@ -48,8 +48,8 @@ def validate_stream(n_keys, n_chunks, seed):
     wall = jnp.int64(_MILLIS + 10_000)
     store = empty_dense_store(n_keys)
 
-    # The reference semantics ARE bench.build_stream_fn — use it, so
-    # the validator can't drift from the contract the bench measures.
+    # The reference semantics ARE changesets.build_stream_fn — the
+    # sequential XLA fold with threaded clocks.
     ref_store, ref_canon = build_stream_fn(n_chunks)(
         store, cs, canonical, jnp.int32(0), wall)
     ref_canon = int(ref_canon)

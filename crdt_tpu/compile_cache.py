@@ -1,8 +1,8 @@
 """JAX's persistent compilation cache for the repo's entry points.
 
-`chip_smoke.py`, `bench.py` and `benchmarks/suite.py` call
-`enable_compile_cache` before their first compile, so a second run on
-the same machine reads its programs back instead of compiling them.
+`chip_smoke.py` calls `enable_compile_cache` before its first compile,
+so a second run on the same machine reads its programs back instead
+of compiling them.
 Neither the package import nor the tests turn the cache on.
 """
 

@@ -6,7 +6,7 @@ host dict for key↔slot); this model is the high-throughput shape: keys
 ARE slot indices ``[0, n_slots)`` and values are int64 scalars (or
 indices into an application-side table, SURVEY.md §7 hard part 4), so
 every operation is a batched array op with zero per-record host work —
-the shape the benchmark's billions-of-merges/sec numbers come from.
+the shape the fan-in cell's billions-of-merges/sec come from.
 
 Replication model (C9/C10 on arrays):
 
@@ -1433,8 +1433,8 @@ class DenseCrdt:
         numpy unpack, C-codec batch HLC formatting, direct string
         assembly (every piece is JSON-plain: int keys, int/null
         values) — byte-identical to the generic encoder but without
-        materializing a Record dict (a 1M-slot export runs in seconds,
-        benchmarks/suite.py `dense_to_json`)."""
+        materializing a Record dict (a 1M-slot export runs in
+        seconds)."""
         self.drain_ingest()
         if key_encoder is None and value_encoder is None:
             fast = self._to_json_fast(modified_since)

@@ -37,11 +37,7 @@ ledger** — every jit-cached device entry point (ops + parallel)
 reports per-kernel dispatch counts, wall-time histograms, a
 (kernel, pow2-bucket) compile census, donation-violation checks, and
 a store-bytes gauge, turning the fast-path zero-dispatch invariants
-into runtime-observable metrics; :mod:`~crdt_tpu.obs.trajectory`
-normalizes every ``bench.py`` run into one
-``benchmarks/history/trajectory.jsonl`` record and verdicts the
-newest run against fastest-of-N floors (``python -m crdt_tpu.obs
-bench --compare``), the CI regression gate.
+into runtime-observable metrics.
 
 Quantile plane (PR 18): :mod:`~crdt_tpu.obs.sketch` is a mergeable
 DDSketch-style **relative-error quantile sketch** — the registry's
@@ -74,8 +70,6 @@ from .probe import CanaryProbe, canary_observed
 from .fleet import (evaluate_slo, format_matrix, lag_matrix,
                     poll_fleet, render_federation)
 from .device import DispatchLedger, default_ledger, pow2_bucket
-from .trajectory import (append_record, compare, load_trajectory,
-                         normalize_record)
 from .render import (format_phase_table, render_prometheus,
                      render_summary, summarize_trace)
 
@@ -97,7 +91,6 @@ __all__ = [
     "poll_fleet", "lag_matrix", "evaluate_slo", "render_federation",
     "format_matrix",
     "DispatchLedger", "default_ledger", "pow2_bucket",
-    "normalize_record", "append_record", "load_trajectory", "compare",
     "render_prometheus", "render_summary", "summarize_trace",
     "format_phase_table",
 ]

@@ -20,12 +20,6 @@ and SLO verdict (see :mod:`crdt_tpu.obs.fleet`)::
 
     python -m crdt_tpu.obs fleet --peers a=127.0.0.1:7000,b=127.0.0.1:7001 --once
 
-The ``bench`` subcommand verdicts the newest bench-trajectory record
-against the fastest-of-N floors of its group — the CI regression gate
-(see :mod:`crdt_tpu.obs.trajectory`)::
-
-    python -m crdt_tpu.obs bench --compare benchmarks/history/trajectory.jsonl
-
 The ``dump`` subcommand fetches a node's SLO flight-recorder bundles
 over the ``debug_dump`` wire op — post-incident forensics without a
 poller having been attached (see :mod:`crdt_tpu.obs.recorder`)::
@@ -146,9 +140,6 @@ def main(argv: Optional[List[str]] = None, out=None) -> int:
     if argv and argv[0] == "fleet":
         from .fleet import fleet_main
         return fleet_main(argv[1:], out)
-    if argv and argv[0] == "bench":
-        from .trajectory import bench_main
-        return bench_main(argv[1:], out)
     if argv and argv[0] == "dump":
         return _dump_main(argv[1:], out)
     ap = argparse.ArgumentParser(

@@ -24,7 +24,7 @@ Exposition (all on the default `MetricsRegistry`, so they ride the
     wall time of the dispatching host call (log2 buckets). Dispatch
     is asynchronous on accelerators — this is enqueue + host prep
     time, not device execution time; fence-inclusive numbers live in
-    the benches.
+    the cells' device traces.
 ``crdt_tpu_device_compiles_total{kernel,bucket}``
     first-call events per (kernel, pow2 size bucket): callers pad
     batch dims to powers of two precisely so the jit cache sees O(log)
@@ -47,10 +47,8 @@ dispatch costs of 100 µs+). It also opens a
 ``jax.profiler.TraceAnnotation`` named after the kernel, so every
 ledger dispatch appears under its own name in a profile, beside the
 device operations it enqueues. ``default_ledger().enabled = False``
-short-circuits ``record()`` to a shared no-op so the bench suite can
-measure the ledger's own overhead differentially
-(``ledger_overhead_frac`` in ``bench.py --mode ingest/--mode sync``,
-budget 5%).
+short-circuits ``record()`` to a shared no-op so a caller can
+measure the ledger's own overhead differentially (budget 5%).
 
 Kernels *register* (by name) at module import of the instrumented
 module, independent of ever dispatching — the crdtlint

@@ -32,8 +32,8 @@ fleet and derives what no single replica can know:
   collide.
 
 Everything below `poll_fleet` is pure (dicts in, dicts/strings out),
-so bench's in-process soaks feed `lag_matrix`/`evaluate_slo` directly
-from replica snapshots without sockets.
+so in-process soaks feed `lag_matrix`/`evaluate_slo` directly from
+replica snapshots without sockets.
 """
 
 from __future__ import annotations

@@ -9,7 +9,7 @@ events) with an optional JSONL sink. Events are plain dicts:
 
 - ``kind`` names the event class: ``merge`` (a merge dispatch span),
   ``gossip_round``, ``wire_frame``, ``checkpoint``, ``breaker``,
-  ``bench_phase``, ``ingest`` (a write-combiner flush span,
+  ``ingest`` (a write-combiner flush span,
   models/ingest.py — carries ``rows`` and ``trigger``).
 - ``hlc`` is the emitting replica's canonical HLC at emission — the
   cluster-orderable stamp. ``mono_s`` (``time.monotonic()``) orders
@@ -21,9 +21,8 @@ events) with an optional JSONL sink. Events are plain dicts:
 ``tracer().enabled`` (one attribute read) first. :func:`span` always
 wraps its body in ``jax.profiler.TraceAnnotation`` — so TPU profiles
 show named merge/pack/wire phases whether or not the ring is on — and
-only times + emits when the ring is enabled. The stream-bench
-per-phase row (bench.py) pins the enabled-overhead at ~0 on the hot
-path.
+only times + emits when the ring is enabled, so the enabled-overhead
+on the hot path is ~0.
 
 HLC arguments may be zero-arg callables; they are invoked only when an
 event is actually recorded, so disabled tracing never pays for a
@@ -58,10 +57,9 @@ def _hlc_str(hlc: Any) -> str:
     """``str(hlc)`` with a small field-keyed cache: emit sites hand
     the SAME canonical stamp to every event between refreshes, so the
     ISO-8601 render (the single biggest per-event cost) is paid once
-    per stamp, not once per event — what keeps the soak-measured
-    tracing overhead inside the 5% budget (bench.py antientropy
-    mode). Keyed on the raw fields, not the object: hashing must not
-    re-render the stamp."""
+    per stamp, not once per event — what keeps the tracing overhead
+    of an anti-entropy soak inside the 5% budget. Keyed on the raw
+    fields, not the object: hashing must not re-render the stamp."""
     if isinstance(hlc, str):
         return hlc
     try:

@@ -210,9 +210,9 @@ def _fanin_stream_kernel(exact_guards, advance_clock, narrow_val,
     in c), so HBM sees each store/changeset lane once per row block
     instead of once per chunk. Chunk ``c`` merges the changeset with
     every logicalTime advanced by ``c`` ms (the steady-state write
-    stream `bench.build_stream_fn` models); results are bit-identical
-    to ``n_chunks`` sequential `fanin_step` folds threading the
-    canonical clock.
+    stream that `benchmarks/changesets.py` `build_stream_fn` models);
+    results are bit-identical to ``n_chunks`` sequential `fanin_step`
+    folds threading the canonical clock.
 
     ``exact_guards`` (static): True runs the column-local shielded
     recv guards in-kernel (~half the per-row compute is the running
@@ -468,8 +468,8 @@ _STREAM_LANE = 1024
 
 def mosaic_auto(n_slots: int, platform: str) -> bool:
     """The ``executor="auto"`` rule every Mosaic path shares (model
-    merges and ingest commits, the sharded fan-in per key shard, the
-    bench): Mosaic lowers on TPU only, over tile-aligned lanes."""
+    merges and ingest commits, the sharded fan-in per key shard):
+    Mosaic lowers on TPU only, over tile-aligned lanes."""
     return platform == "tpu" and n_slots % TILE == 0
 
 

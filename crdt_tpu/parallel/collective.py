@@ -36,9 +36,9 @@ member, in slot order) — one dispatch yields everything
 `CollectiveGroup.join` needs, and what it fetches does not grow with
 the store.
 
-On a real pod the member axis rides ICI; on the 1-core virtual mesh
-(tests, `bench.py --mode collective`) the same program runs across
-virtual CPU devices — bit-identical results, honest-downscale timing.
+On a real pod the member axis rides ICI; on the tests' 1-core virtual
+mesh the same program runs across virtual CPU devices — bit-identical
+results, honest-downscale timing.
 """
 
 from __future__ import annotations

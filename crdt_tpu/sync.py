@@ -160,8 +160,8 @@ def sync_collective(group):
 class MerkleSyncReport:
     """What one in-process anti-entropy round cost
     (:func:`sync_merkle`) — the accounting the socket path keeps in
-    metrics/WireTally, exposed as a plain object so topology benches
-    (bench.py --mode antientropy) can sum traffic without sockets.
+    metrics/WireTally, exposed as a plain object so a topology of
+    in-process replicas can sum traffic without sockets.
     ``digest_bytes`` models the walk's wire cost (8 bytes per digest
     value, both directions); ``payload_bytes`` is the packed arenas'
     exact size. An empty ``ranges`` means the trees matched and no

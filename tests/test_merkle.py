@@ -332,8 +332,7 @@ def test_divergence_proportional_bytes_vs_full_scan():
     """The acceptance ratio: a converged pair diverging in one small
     slot window re-syncs for <10% of the full-scan pack bytes. The
     walk's fixed cost is logarithmic meta traffic, so the ratio only
-    tightens as the store grows (bench.py --mode sync measures the
-    4096-slot headline)."""
+    tightens as the store grows."""
     n = 2048
     a = _make("a", n)
     b = _make("b", n)
